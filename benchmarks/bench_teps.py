@@ -162,11 +162,11 @@ def _cohort_vs_vmap(graph, seed):
 
     dg = session.device_graph()
     base = jax.jit(
-        lambda rr: jax.vmap(lambda r: B.search_state(dg, r, cfg))(rr))
+        lambda d, rr: jax.vmap(lambda r: B.search_state(d, r, cfg))(rr))
     dev_roots = jnp.asarray(roots, jnp.int32)
-    jax.block_until_ready(base(dev_roots).frontier)   # compile outside
+    jax.block_until_ready(base(dg, dev_roots).frontier)  # compile outside
     t0 = time.perf_counter()
-    st = base(dev_roots)
+    st = base(dg, dev_roots)
     jax.block_until_ready(st.frontier)
     vmap_s = time.perf_counter() - t0
     _, level_v = B.finalize(st)
@@ -346,7 +346,9 @@ def main(argv=None):
 
     from repro.core import graph as G
     from repro.core.bfs import BFSConfig
+    from repro.runtime import enable_compile_cache
 
+    enable_compile_cache()
     on_tpu = jax.default_backend() == "tpu"
     kscale = args.scale if on_tpu else min(args.scale, args.kernel_scale)
     n_dev = len(jax.devices())

@@ -15,7 +15,14 @@ def emit(name: str, us_per_call: float, derived: str = ""):
 
 
 def run_with_devices(module: str, n_devices: int, args=(), timeout=900):
+    """Run `python -m module` on N emulated CPU devices.
+
+    A CPU rehearsal of a multi-device path (the figure scripts' partition
+    sweeps), never a chip measurement: the child is pinned to
+    `JAX_PLATFORMS=cpu`, so it cannot contend for an accelerator the parent
+    process may hold."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     res = subprocess.run([sys.executable, "-m", module, *map(str, args)],

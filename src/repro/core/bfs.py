@@ -30,9 +30,14 @@ Two interchangeable formulations of the per-level steps exist:
   heuristic nor the loop condition re-scans the frontier.
 
 Both produce bitwise-identical parent/level arrays (gated by
-tests/test_kernel_bfs.py); `backend_kernels=None` auto-enables the kernel
-path on TPU backends and keeps XLA elsewhere (where the kernels only run
-under the Pallas interpreter).
+tests/test_kernel_bfs.py); `backend_kernels=None` runs the XLA path on every
+backend. The kernels are opt-in (`backend_kernels=True` / `REPRO_KERNELS=on`):
+Mosaic still refuses each of them for TPU (tests/test_tpu_compile.py).
+
+Every compiled program takes the graph (`DeviceGraph`, ELL tiles, hub row
+list) as jit *arguments*, never as closed-over constants: a scale-22 CSR is
+about 0.5 GB, and a constant copy per executable would bloat compiles,
+serialized plans and device memory alike.
 
 All vertex/edge indices are int32 (per-partition E < 2**31; the multi-pod
 sharding in `hybrid_bfs.py` keeps per-device edge counts far below this).
@@ -41,7 +46,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -85,45 +90,52 @@ class BFSConfig:
     hub_split: bool = False       # enable hub/tail split per-level dispatch
     hub_deg: int = 256            # hub threshold (snapped to bucket ladder)
     hub_slab: int = 256           # neighbour slots per hub-side pull slab
-    # Pallas kernel path over ELL tiles. None = auto: real Mosaic lowering on
-    # TPU backends, XLA reference path elsewhere (where kernels would run
-    # under the interpreter). Explicit True forces the kernel path anywhere
-    # (interpret mode off-TPU — the CI equivalence configuration).
+    # Pallas kernel path over ELL tiles. None defers to REPRO_KERNELS
+    # (default off: the XLA step on every backend, since Mosaic refuses the
+    # kernels for TPU; see kernels_enabled). Explicit True forces the kernel
+    # path anywhere (interpret mode off-TPU — the CI equivalence
+    # configuration).
     backend_kernels: Optional[bool] = None
 
 
-def kernels_enabled(cfg: BFSConfig) -> bool:
-    """Resolve `cfg.backend_kernels`.
+def kernels_enabled(cfg: BFSConfig, runtime=None) -> bool:
+    """Resolve `cfg.backend_kernels`: the one place the kernel policy lives.
 
-    None defers to `RuntimeConfig.kernel_backend` (REPRO_KERNELS):
-    'on'/'off' force the kernel path globally without touching per-query
-    configs; 'auto' keeps the old behavior — real Mosaic lowering on TPU
-    backends only. An explicit `BFSConfig.backend_kernels` always wins
-    (per-query beats process-wide).
+    An explicit `BFSConfig.backend_kernels` always wins (per-query beats
+    process-wide). None defers to `runtime.kernel_backend` (REPRO_KERNELS,
+    'on' | 'off', default 'off'), where `runtime` is a session's private
+    `RuntimeConfig` or, when omitted, the process config. Off runs the XLA
+    step: Mosaic still refuses each Pallas kernel for TPU
+    (tests/test_tpu_compile.py marks the refusals as strict xfails).
     """
-    if cfg.backend_kernels is None:
+    if cfg.backend_kernels is not None:
+        return cfg.backend_kernels
+    if runtime is None:
         from repro.runtime.config import get_runtime_config
-        mode = get_runtime_config().kernel_backend
-        if mode == "on":
-            return True
-        if mode == "off":
-            return False
-        return jax.default_backend() == "tpu"
-    return cfg.backend_kernels
+        runtime = get_runtime_config()
+    return runtime.kernel_backend == "on"
 
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class DeviceGraph:
-    """CSR graph as device arrays (+ one-slot padding for queue-fill gathers)."""
-    indptr: jax.Array    # int32[V+1]
-    indices: jax.Array   # int32[E]
-    deg_ext: jax.Array   # int32[V+1]; deg_ext[V] == 0 (fill-vertex degree)
+    """CSR graph as device arrays (+ one-slot padding for queue-fill gathers).
+
+    `pull_order` lists the rows a bottom-up pass may scan — every row of
+    nonzero degree, by descending degree — then fill ids (V). Pulling rows
+    in that order groups rows of like degree into one chunk, so a chunk's
+    slab loop is not held open by one wide row among narrow ones, and the
+    zero-degree rows (which can never find a parent) cost no chunk at all.
+    """
+    indptr: jax.Array      # int32[V+1]
+    indices: jax.Array     # int32[E]
+    deg_ext: jax.Array     # int32[V+1]; deg_ext[V] == 0 (fill-vertex degree)
+    pull_order: jax.Array  # int32[V]; pullable rows, widest first; fill V
     num_vertices: int
     num_directed_edges: int
 
     def tree_flatten(self):
-        return ((self.indptr, self.indices, self.deg_ext),
+        return ((self.indptr, self.indices, self.deg_ext, self.pull_order),
                 (self.num_vertices, self.num_directed_edges))
 
     @classmethod
@@ -138,10 +150,15 @@ class DeviceGraph:
         # Edgeless graphs keep one dummy slot so gathers stay well-formed
         # (never addressed: every edge-slot predicate is False when E == 0).
         indices = g.indices if g.num_directed_edges else np.zeros(1, np.int32)
+        order = np.argsort(-g.degrees.astype(np.int64), kind="stable")
+        pull_order = np.full(g.num_vertices, g.num_vertices, np.int32)
+        pullable = int(np.count_nonzero(g.degrees))
+        pull_order[:pullable] = order[:pullable]
         return cls(
             indptr=jnp.asarray(g.indptr, dtype=jnp.int32),
             indices=jnp.asarray(indices, dtype=jnp.int32),
             deg_ext=jnp.asarray(deg_ext),
+            pull_order=jnp.asarray(pull_order),
             num_vertices=g.num_vertices,
             num_directed_edges=g.num_directed_edges,
         )
@@ -191,9 +208,7 @@ def _top_down_step(dg: DeviceGraph, cfg: BFSConfig, frontier, visited, parent,
     """One push level: work ~ frontier edge mass, chunked.
 
     Takes the flat (frontier, visited, parent) triple rather than a
-    `BFSState` so the batched cohort path can `vmap` it per lane with a
-    masked frontier — a lane whose frontier is zeroed contributes zero edge
-    slots and therefore zero chunk iterations to the batched while-loop.
+    `BFSState` so the batched cohort path can run it lane by lane.
 
     `dst_mask` (bool[V] or None) restricts which DESTINATIONS this pass may
     discover — the heterogeneous split's side filter. The scatter-min parent
@@ -241,24 +256,27 @@ def _bottom_up_step(dg: DeviceGraph, cfg: BFSConfig, frontier, visited,
                     parent_in, row_mask=None, chunk=None, slab=None):
     """One pull level: row chunks x adjacency slabs with block early exit.
 
-    `row_mask` (scalar/broadcastable bool, cohort membership under `vmap`)
-    masks the unvisited scan: a masked-out lane compacts an empty row queue
-    and contributes zero chunk iterations — no pull work at all. The
-    heterogeneous split passes a per-vertex side mask here, plus side-tuned
-    `chunk`/`slab` overrides (defaults: `cfg.bu_chunk`/`cfg.bu_slab`): the
-    per-row first-hit parent is invariant under chunk grouping and slab
-    width (first hit == lowest adjacency slot regardless of how slots are
-    grouped), so any side partition of the rows produces bitwise-identical
-    flags and parents to one unsplit pass — splitting only changes how many
-    slab iterations a chunk's widest row can force on its neighbours.
+    Rows are pulled in `dg.pull_order` (nonzero degree, widest first), so
+    a chunk holds rows of like degree. `row_mask` (bool[V] or None)
+    restricts the unvisited scan: the heterogeneous split passes a
+    per-vertex side mask here, plus side-tuned `chunk`/`slab` overrides
+    (defaults: `cfg.bu_chunk`/`cfg.bu_slab`). The per-row first-hit parent
+    is invariant under chunk grouping, row order and slab width (first hit
+    == lowest adjacency slot regardless of how slots are grouped), so any
+    side partition or order of the rows produces bitwise-identical flags
+    and parents to one unsplit pass — they only change how many slab
+    iterations a chunk's widest row can force on its neighbours.
     """
     v = dg.num_vertices
-    r = min(chunk or cfg.bu_chunk, dg.num_vertices)
+    r = min(chunk or cfg.bu_chunk, v)
     w = slab or cfg.bu_slab
-    unvisited = (visited == 0).astype(jnp.uint8)
+    order = dg.pull_order
+    order_rows = jnp.minimum(order, v - 1)
+    pull = (order < v) & (visited[order_rows] == 0)
     if row_mask is not None:
-        unvisited = unvisited * row_mask.astype(jnp.uint8)
-    queue, m = fr.compact(unvisited)             # fill entries == v
+        pull = pull & row_mask[order_rows]
+    at, m = fr.compact(pull.astype(jnp.uint8))   # positions in pull_order
+    queue = jnp.where(at < v, order[jnp.minimum(at, v - 1)], v)  # fill == v
 
     def chunk_body(carry):
         base, next_flags, parent = carry
@@ -424,10 +442,20 @@ def _resolve_ell(dg: DeviceGraph, cfg: BFSConfig, ell):
     return ell
 
 
+_advance_jit = jax.jit(_advance, static_argnums=(1,))
+_init_state_jit = jax.jit(init_state)
+
+
 def make_level_step(dg: DeviceGraph, cfg: BFSConfig, ell=None):
-    """Returns a jitted `state -> state` advancing one BFS level."""
+    """Returns `state -> state` advancing one BFS level (one jitted
+    program; `dg` and `ell` are bound as its arguments)."""
     ell = _resolve_ell(dg, cfg, ell)
-    return jax.jit(functools.partial(_advance, dg, cfg, ell))
+    return functools.partial(_advance_jit, dg, cfg, ell)
+
+
+def make_init(dg: DeviceGraph):
+    """Returns jitted `root -> BFSState`, the graph bound as an argument."""
+    return functools.partial(_init_state_jit, dg)
 
 
 def search_state(dg: DeviceGraph, root, cfg: BFSConfig, ell=None) -> BFSState:
@@ -444,8 +472,8 @@ def search_state(dg: DeviceGraph, root, cfg: BFSConfig, ell=None) -> BFSState:
     engine's batched fused path does.
 
     When `kernels_enabled(cfg)`, pass `ell` (degree-bucketed tiles from
-    `repro.core.ell` / `GraphSession.ell_tiles`); it is closed over by the
-    per-level steps alongside the CSR arrays.
+    `repro.core.ell` / `GraphSession.ell_tiles`); jit this function with
+    `dg`, `root` and `ell` as arguments (cfg static), as `bfs()` does.
     """
     ell = _resolve_ell(dg, cfg, ell)
     st = init_state(dg, root)
@@ -477,6 +505,27 @@ _bfs_jit = jax.jit(search_state, static_argnums=(2,))
 # traceable pieces (`init_batch`, `make_batch_step`, `batch_scalars`).
 
 BATCH_VARIANTS = ("td", "bu", "mixed")
+
+
+class CohortGraph(NamedTuple):
+    """The graph-side arguments of every cohort step executable.
+
+    A pytree, passed to the jitted step as an argument (never closed over):
+    the CSR arrays, the ELL tiles when the kernel path runs (else None),
+    and the static hub row list when `hub_split` is on (else None).
+    """
+    dg: DeviceGraph
+    ell: Optional[tuple]
+    hub_rows: Optional[jax.Array]    # int32[H]
+
+
+def hub_rows(degrees: np.ndarray, hub_deg: int) -> jax.Array:
+    """int32[H]: the rows above the snapped hub floor (`_hub_row_mask`'s
+    true set), listed on the host — a property of the graph, not of any
+    search, so it is built once per session and passed to the steps."""
+    floor = ELL.hub_degree_floor(hub_deg)
+    return jnp.asarray(np.flatnonzero(np.asarray(degrees) > floor)
+                       .astype(np.int32))
 
 
 @jax.tree_util.register_pytree_node_class
@@ -620,30 +669,45 @@ def _decide_direction_batch(dg: DeviceGraph, cfg: BFSConfig, bu_mode,
     return bu, jnp.where(bu, bu_steps + 1, 0)
 
 
+def _lane_by_lane(step, frontier, visited, parent, mask):
+    """Run the single-root `step(f, vis, par) -> (flags, parent)` for every
+    lane in `mask`, one lane after another; lanes outside it produce no
+    flags and keep their parents, at no cost.
+
+    Lanes run in sequence because the per-lane loops then keep scalar trip
+    counts and plain 1-D gathers and scatters. Under `vmap` a `while_loop`
+    predicate becomes batched (JAX then selects each `[B, V]` carry on
+    every trip) and the gathers and scatters gain a batch dimension: on a
+    v5e chip a 16-lane bottom-up level written that way took 25-40 times as
+    long as a whole single-root search.
+    """
+    def lane(args):
+        f, vis, par, on = args
+        return jax.lax.cond(on, lambda: step(f, vis, par),
+                            lambda: (jnp.zeros_like(f), par))
+
+    return jax.lax.map(lane, (frontier, visited, parent, mask))
+
+
 def _top_down_step_batch(dg: DeviceGraph, cfg: BFSConfig, frontier, visited,
                          parent, mask, dst_mask=None):
-    """XLA push over the top-down cohort: lanes outside `mask` get a zeroed
-    frontier, so they contribute zero edge slots to the batched while-loop
-    (its trip count is the max edge mass over the COHORT, not the batch).
-    `dst_mask` (bool[V], lane-invariant) is the split's side filter."""
-    masked = frontier * mask[:, None].astype(frontier.dtype)
-    return jax.vmap(
-        lambda f, vis, par: _top_down_step(dg, cfg, f, vis, par, dst_mask))(
-            masked, visited, parent)
+    """XLA push over the top-down cohort, lane by lane. `dst_mask`
+    (bool[V], lane-invariant) is the split's side filter."""
+    return _lane_by_lane(
+        lambda f, vis, par: _top_down_step(dg, cfg, f, vis, par, dst_mask),
+        frontier, visited, parent, mask)
 
 
 def _bottom_up_step_batch(dg: DeviceGraph, cfg: BFSConfig, frontier, visited,
                           parent, mask, side=None, chunk=None, slab=None):
-    """XLA pull over the bottom-up cohort: masked-out lanes compact an empty
-    row queue and contribute zero chunk iterations. `side` (bool[V],
+    """XLA pull over the bottom-up cohort, lane by lane. `side` (bool[V],
     lane-invariant) restricts the unvisited scan to one split side, with
     side-tuned `chunk`/`slab` geometry."""
-    return jax.vmap(
-        lambda f, vis, par, m: _bottom_up_step(
-            dg, cfg, f, vis, par,
-            row_mask=(m & side) if side is not None else m,
-            chunk=chunk, slab=slab))(
-            frontier, visited, parent, mask)
+    return _lane_by_lane(
+        lambda f, vis, par: _bottom_up_step(dg, cfg, f, vis, par,
+                                            row_mask=side, chunk=chunk,
+                                            slab=slab),
+        frontier, visited, parent, mask)
 
 
 def _hub_pull_batch(dg: DeviceGraph, cfg: BFSConfig, hub_rows, frontier,
@@ -651,8 +715,9 @@ def _hub_pull_batch(dg: DeviceGraph, cfg: BFSConfig, hub_rows, frontier,
     """Dense pull over the STATIC hub row set, vmapped across lanes.
 
     Hub membership is a property of the graph (`deg > hub_degree_floor`),
-    not of the search, so the row list is a trace-time constant: the hub
-    pull needs no queue compaction (the tail pays one O(V) compact; the
+    not of the search, so the row list has a static length (it is built once
+    on the host and passed in with the graph, `CohortGraph.hub_rows`): the
+    hub pull needs no queue compaction (the tail pays one O(V) compact; the
     hub none) and no chunked while-loop — one slab scan over all H rows,
     H being hundreds even at scale 22 (a row in the hub needs > floor
     edges, so H <= 2E/floor). Settled/masked rows carry degree 0 and the
@@ -745,7 +810,7 @@ def _bottom_up_step_kernels_batch(dg: DeviceGraph, cfg: BFSConfig, ell,
     return next_flags, parent
 
 
-def _advance_batch(dg: DeviceGraph, cfg: BFSConfig, ell, variant: str,
+def _advance_batch(cfg: BFSConfig, variant: str, graph: CohortGraph,
                    st: BatchState) -> BatchState:
     """One cohort level: at most one top-down plus one bottom-up pass, each
     over its masked cohort — never both per lane.
@@ -766,6 +831,7 @@ def _advance_batch(dg: DeviceGraph, cfg: BFSConfig, ell, variant: str,
     direction passes, each self-annihilating when its cohort is empty.
     """
     i32 = jnp.int32
+    dg, ell = graph.dg, graph.ell
     use_kernels = kernels_enabled(cfg)
     b, v = st.frontier.shape
     next_flags = jnp.zeros((b, v), jnp.uint8)
@@ -796,11 +862,8 @@ def _advance_batch(dg: DeviceGraph, cfg: BFSConfig, ell, variant: str,
         hub_v = _hub_row_mask(dg, cfg)
         tail_pull = ~hub_v & (dg.deg_ext[:-1] > 0)   # deg-0 rows never pull
         # The hub row LIST is static (graph property, not search state):
-        # dg's arrays are trace-time constants here, so this host read
-        # happens once per executable build, like the ELL tile build.
-        hub_rows = jnp.asarray(np.flatnonzero(
-            np.asarray(dg.deg_ext)[:-1] > ELL.hub_degree_floor(cfg.hub_deg)
-        ).astype(np.int32))
+        # built once on the host (`hub_rows`) and passed in with the graph.
+        hub_list = graph.hub_rows
         if use_kernels:
             ell_tail, ell_hub = ELL.split_tiles(ell, cfg.hub_deg)
 
@@ -818,9 +881,9 @@ def _advance_batch(dg: DeviceGraph, cfg: BFSConfig, ell, variant: str,
                     dg, cfg, ell_hub if hub_side else ell_tail, st.frontier,
                     st.visited, par, lane_mask, hub_kernel=hub_side)
             if hub_side:
-                if hub_rows.shape[0] == 0:
+                if hub_list.shape[0] == 0:
                     return jnp.zeros_like(st.frontier), par
-                return _hub_pull_batch(dg, cfg, hub_rows, st.frontier,
+                return _hub_pull_batch(dg, cfg, hub_list, st.frontier,
                                        st.visited, par, lane_mask)
             # Tail-tuned chunking is the split's other XLA win: tail rows
             # are degree-bounded by the snapped hub floor, so one wide row
@@ -900,19 +963,22 @@ def reachable_variants(cfg: BFSConfig) -> tuple[str, ...]:
     return BATCH_VARIANTS
 
 
-def make_batch_step(dg: DeviceGraph, cfg: BFSConfig, variant: str, ell=None):
-    """Raw traceable `BatchState -> BatchState` for one cohort step variant.
+def make_batch_step(cfg: BFSConfig, variant: str):
+    """Raw traceable `(CohortGraph, BatchState) -> BatchState` for one cohort
+    step variant.
 
     `variant` is one of `BATCH_VARIANTS` ("td" | "bu" | "mixed"); the engine
     compiles all three per (config, batch bucket) and the driver backend
     dispatches whichever matches the level's cohort occupancy. Jit-wrap the
-    result yourself (`repro.engine` caches it on the session).
+    result yourself (`repro.engine` caches it on the session) and pass the
+    graph (`CohortGraph`; `GraphSession.cohort_graph` builds it) on every
+    call: it is an argument of the executable, so one compiled step serves
+    any graph of the same shapes.
     """
     if variant not in BATCH_VARIANTS:
         raise ValueError(f"variant must be one of {BATCH_VARIANTS}, "
                          f"got {variant!r}")
-    ell = _resolve_ell(dg, cfg, ell)
-    return functools.partial(_advance_batch, dg, cfg, ell, variant)
+    return functools.partial(_advance_batch, cfg, variant)
 
 
 def batch_scalars(st: BatchState) -> dict:
@@ -994,8 +1060,7 @@ def bfs_instrumented(g: Graph | DeviceGraph, root: int,
     """
     from repro.engine.level_loop import LevelDriver, SingleStepBackend
     dg = g if isinstance(g, DeviceGraph) else DeviceGraph.from_graph(g)
-    backend = SingleStepBackend(
-        jax.jit(lambda r: init_state(dg, r)), make_level_step(dg, cfg),
-        dg.num_vertices)
+    backend = SingleStepBackend(make_init(dg), make_level_step(dg, cfg),
+                                dg.num_vertices)
     parent, level, stats, _timings = LevelDriver(backend).run(int(root))
     return parent, level, stats

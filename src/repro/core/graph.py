@@ -96,24 +96,35 @@ def from_edges(src: np.ndarray, dst: np.ndarray, num_vertices: int,
     degrees = np.bincount(src, minlength=num_vertices).astype(np.int32)
     indptr = np.zeros(num_vertices + 1, dtype=np.int64)
     np.cumsum(degrees, out=indptr[1:])
-    order = np.argsort(src, kind="stable")
-    indices = dst[order]
+    # One stable sort by row (and, for the paper's ordering, by descending
+    # neighbour degree within a row); ties keep their edge-list order.
+    key = _row_major_key(src, degrees[dst] if sort_by_degree else None,
+                         degrees)
+    indices = dst[np.argsort(key, kind="stable")]
     g = Graph(num_vertices, indptr, indices, degrees)
-    if sort_by_degree:
-        g = sort_adjacency_by_degree(g)
     g.validate()
     return g
 
 
+def _row_major_key(rows: np.ndarray, col_deg: np.ndarray | None,
+                   degrees: np.ndarray) -> np.ndarray:
+    """int64 sort key: row-major, then descending `col_deg` within a row.
+
+    `row * (D+1) + (D - col_deg)` with D the max degree stays below 2**62
+    for V, D < 2**31, so one stable argsort replaces a lexsort.
+    """
+    if col_deg is None:
+        return rows
+    d = int(degrees.max()) if degrees.size else 0
+    return rows * (d + 1) + (d - col_deg.astype(np.int64))
+
+
 def sort_adjacency_by_degree(g: Graph) -> Graph:
     """Reorder each adjacency list by descending neighbour degree (§3.4)."""
-    # Sort key per directed edge: (row, -deg[col]). One global stable argsort.
     row_of_edge = np.repeat(
         np.arange(g.num_vertices, dtype=np.int64), g.degrees)
-    neg_deg = -g.degrees[g.indices].astype(np.int64)
-    # Composite key: row * (max_deg+1) + rank(neg_deg) would overflow; use
-    # lexsort (last key is primary).
-    order = np.lexsort((neg_deg, row_of_edge))
+    key = _row_major_key(row_of_edge, g.degrees[g.indices], g.degrees)
+    order = np.argsort(key, kind="stable")
     return Graph(g.num_vertices, g.indptr, g.indices[order], g.degrees)
 
 
@@ -133,13 +144,20 @@ def rmat(scale: int, edgefactor: int = EDGEFACTOR, seed: int = 0,
     ab = a + b
     c_norm = c / (1.0 - ab)
     a_norm = a / ab
+    u = np.empty(m)
+    v = np.empty(m)
+    ii = np.empty(m, dtype=bool)
     for _ in range(scale):
-        u = rng.random(m)
-        v = rng.random(m)
-        ii = u > ab
-        jj = np.where(ii, v > c_norm, v > a_norm)
-        src = (src << 1) | ii
-        dst = (dst << 1) | jj
+        rng.random(out=u)
+        rng.random(out=v)
+        np.greater(u, ab, out=ii)
+        src <<= 1
+        src |= ii
+        # quadrant column bit: v against c_norm below the split, a_norm above
+        np.copyto(u, a_norm)
+        u[ii] = c_norm
+        dst <<= 1
+        dst |= v > u
     if permute:
         perm = rng.permutation(n)
         src, dst = perm[src], perm[dst]

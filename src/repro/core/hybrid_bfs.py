@@ -35,19 +35,18 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import ell as ELL
 from repro.core import frontier as fr
 from repro.core.bfs import BFSConfig, INT_MAX, kernels_enabled
 from repro.core.partition import PartitionedGraph, PartitionPlan, unpermute, unpermute_ids
 from repro.kernels import ops as K
-from repro.parallel.collectives import shard_map_compat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -275,11 +274,9 @@ def _init_mf_dec(root, deg, dec_hub: int):
     return jnp.where(root < dec_hub, deg[root], 0) if dec_hub else deg[root]
 
 
-def _resolve_hybrid_ell(pg: PartitionedGraph, cfg: BFSConfig, ell):
+def _hybrid_ell(pg: PartitionedGraph, cfg: BFSConfig):
     """Stacked per-device tiles for the kernel path; () when XLA runs."""
-    if not kernels_enabled(cfg):
-        return ()
-    return ELL.build_hybrid_ell(pg) if ell is None else ell
+    return ELL.build_hybrid_ell(pg) if kernels_enabled(cfg) else ()
 
 
 # -------------------------------------------------------------- level loop --
@@ -381,8 +378,9 @@ def default_mesh(n_parts: int, axis_name: str = "part") -> Mesh:
     if len(devs) < n_parts:
         raise RuntimeError(
             f"need {n_parts} devices for {n_parts} partitions, have "
-            f"{len(devs)} (set XLA_FLAGS=--xla_force_host_platform_"
-            f"device_count={n_parts})")
+            f"{len(devs)} {devs[0].platform} device(s); on a CPU host, "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={n_parts} "
+            f"emulates them")
     return Mesh(np.array(devs[:n_parts]), (axis_name,))
 
 
@@ -400,47 +398,71 @@ def make_root_mapper(plan: PartitionPlan):
     return root_mapper
 
 
-def make_hybrid_search(pg: PartitionedGraph,
-                       hcfg: HybridConfig = HybridConfig(),
-                       mesh: Optional[Mesh] = None, ell=None):
-    """Build the partitioned whole-search callable (public compile target).
+class HybridGraph(NamedTuple):
+    """A partitioning's device arrays: the sharded programs' graph arguments.
 
-    Returns `(search_fn, root_mapper)`. `search_fn(root_new)` is a pure
-    traceable function (graph arrays closed over) mapping a *new-id* root to
-    `(parent_new, level_new, levels)` in the padded id space; wrap it in
-    `jax.jit` once and reuse it across roots — `repro.engine` caches exactly
-    that executable per (graph, plan, config). `root_mapper` translates
-    original ids; `finalize_hybrid` maps results back.
-
-    `ell` (stacked per-device tiles from `ell.build_hybrid_ell`) feeds the
-    `backend_kernels` path; it is built on the fly when omitted —
-    `GraphSession.hybrid_ell` caches it across searches.
+    The `[P, ...]` arrays (and the stacked ELL tiles) are split over the mesh
+    axis, one partition per device; `deg_ext` is replicated. Passed to every
+    compiled sharded program as an argument, never closed over.
     """
-    plan = pg.plan
-    if mesh is None:
-        mesh = default_mesh(plan.n_parts, hcfg.axis_name)
-    v_pad, r = plan.v_pad, pg.num_local_rows
-    e_local = pg.local_indices.shape[1]
-    pg_shapes = (v_pad, r, e_local)
-    ell = _resolve_hybrid_ell(pg, hcfg.bfs, ell)
+    indptr: jax.Array     # int32[P, R+1]
+    indices: jax.Array    # int32[P, Emax]
+    row_gid: jax.Array    # int32[P, R]
+    deg_ext: jax.Array    # int32[v_pad+1]
+    ell: tuple            # stacked per-device ELL tiles; () on the XLA path
 
-    fn = functools.partial(_device_bfs, pg_shapes, pg.total_directed_edges,
-                           plan.hub_count, hcfg)
+
+@dataclasses.dataclass(frozen=True)
+class HybridShapes:
+    """The static facts a sharded program is compiled for."""
+    v_pad: int
+    rows: int             # local rows per device (R)
+    e_local: int          # padded local edge slots per device (Emax)
+    e_total: int          # directed edges of the whole graph
+    hub_count: int
+
+    @classmethod
+    def of(cls, pg: PartitionedGraph) -> "HybridShapes":
+        return cls(pg.plan.v_pad, pg.num_local_rows,
+                   pg.local_indices.shape[1], pg.total_directed_edges,
+                   pg.plan.hub_count)
+
+    @property
+    def local(self) -> tuple:
+        return (self.v_pad, self.rows, self.e_local)
+
+
+def place_hybrid_graph(pg: PartitionedGraph, mesh: Mesh, axis_name: str,
+                       ell=()) -> HybridGraph:
+    """Commit a partitioning to the mesh: partition p's rows on device p."""
+    split = NamedSharding(mesh, P(axis_name))
+    rep = NamedSharding(mesh, P())
+    return HybridGraph(
+        indptr=jax.device_put(pg.local_indptr, split),
+        indices=jax.device_put(pg.local_indices, split),
+        row_gid=jax.device_put(pg.local_row_gid, split),
+        deg_ext=jax.device_put(pg.deg_ext, rep),
+        ell=jax.device_put(ell, split))
+
+
+def hybrid_search_program(shapes: HybridShapes, hcfg: HybridConfig,
+                          mesh: Mesh):
+    """The partitioned whole search as a pure traceable function
+    `(HybridGraph, root_new) -> (parent_new, level_new, levels)`."""
+    fn = functools.partial(_device_bfs, shapes.local, shapes.e_total,
+                           shapes.hub_count, hcfg)
     ax = hcfg.axis_name
-    shmapped = shard_map_compat(
+    shmapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(ax), P(ax), P(ax), P(), P(ax), P()),
-        out_specs=(P(), P(), P()))
-    gl_indptr = jnp.asarray(pg.local_indptr)
-    gl_indices = jnp.asarray(pg.local_indices)
-    gl_rowgid = jnp.asarray(pg.local_row_gid)
-    gl_degext = jnp.asarray(pg.deg_ext)
+        out_specs=(P(), P(), P()), check_vma=False)
 
-    def search_fn(root_new):
-        return shmapped(gl_indptr, gl_indices, gl_rowgid, gl_degext, ell,
+    def search_fn(graph: HybridGraph, root_new):
+        return shmapped(graph.indptr, graph.indices, graph.row_gid,
+                        graph.deg_ext, graph.ell,
                         jnp.asarray(root_new, jnp.int32))
 
-    return search_fn, make_root_mapper(plan)
+    return search_fn
 
 
 def finalize_hybrid(plan: PartitionPlan, parent_new, level_new):
@@ -461,12 +483,17 @@ def hybrid_bfs(pg: PartitionedGraph, root_orig: int,
 
     `root_orig` is in original vertex ids; results are mapped back through the
     plan's permutation (parents as original ids, -1 unreached). One-shot
-    convenience: compiles per call. For repeated queries use `repro.engine`,
-    which caches the executable built by `make_hybrid_search`.
+    convenience: places the graph and compiles per call. For repeated
+    queries use `repro.engine`, which caches the placed graph
+    (`GraphSession.hybrid_graph`) and the `hybrid_search_program` executable.
     """
-    search_fn, root_mapper = make_hybrid_search(pg, hcfg, mesh)
-    run = jax.jit(search_fn)
-    parent_new, level_new, levels = run(jnp.int32(root_mapper(root_orig)))
+    if mesh is None:
+        mesh = default_mesh(pg.plan.n_parts, hcfg.axis_name)
+    graph = place_hybrid_graph(pg, mesh, hcfg.axis_name,
+                               _hybrid_ell(pg, hcfg.bfs))
+    run = jax.jit(hybrid_search_program(HybridShapes.of(pg), hcfg, mesh))
+    root = jnp.int32(make_root_mapper(pg.plan)(root_orig))
+    parent_new, level_new, levels = run(graph, root)
     parent, level = finalize_hybrid(pg.plan, parent_new, level_new)
     return parent, level, int(levels)
 
@@ -474,7 +501,8 @@ def hybrid_bfs(pg: PartitionedGraph, root_orig: int,
 # -------------------------------------------------- instrumented BSP loop --
 
 def make_hybrid_stepper(pg: PartitionedGraph, hcfg: HybridConfig,
-                        mesh: Optional[Mesh] = None, ell=None):
+                        mesh: Optional[Mesh] = None,
+                        graph: Optional[HybridGraph] = None):
     """Level-by-level driver pieces for the Fig. 3/4 benchmarks.
 
     Returns (init_fn, compute_fn, exchange_fn, finalize_fn, root_mapper):
@@ -488,31 +516,33 @@ def make_hybrid_stepper(pg: PartitionedGraph, hcfg: HybridConfig,
     State carries the frontier statistics (`nf` full count, `mf` full edge
     mass, `mf_dec` the direction-decision statistic) so the host loop reads
     two scalars per level instead of re-reducing the V-byte frontier.
+
+    `graph` is the partitioning already committed to `mesh`
+    (`place_hybrid_graph`; `GraphSession.hybrid_graph` caches one); it is
+    placed here when omitted. The returned pieces bind it as an argument
+    of their jitted programs.
     """
     plan = pg.plan
     n = plan.n_parts
     if mesh is None:
         mesh = default_mesh(n, hcfg.axis_name)
-    v_pad, r = plan.v_pad, pg.num_local_rows
-    e_local = pg.local_indices.shape[1]
-    pg_shapes = (v_pad, r, e_local)
+    shapes = HybridShapes.of(pg)
+    v_pad, pg_shapes = shapes.v_pad, shapes.local
     cfg = hcfg.bfs
     use_kernels = kernels_enabled(cfg)
-    ell = _resolve_hybrid_ell(pg, cfg, ell)
     ax = hcfg.axis_name
-
-    gl_indptr = jnp.asarray(pg.local_indptr)
-    gl_indices = jnp.asarray(pg.local_indices)
-    gl_rowgid = jnp.asarray(pg.local_row_gid)
-    gl_degext = jnp.asarray(pg.deg_ext)
-    deg = gl_degext[:-1]
+    if graph is None:
+        graph = place_hybrid_graph(pg, mesh, ax,
+                                   _hybrid_ell(pg, cfg))
     dec_hub = _dec_hub(hcfg, plan.hub_count)
 
-    def init_fn(root):
+    @jax.jit
+    def init_prog(g: HybridGraph, root):
+        deg = g.deg_ext[:-1]
         visited = jnp.zeros(v_pad, jnp.uint8).at[root].set(1)
         pcand = jnp.full((n, v_pad), INT_MAX, jnp.int32).at[:, root].set(root)
         lcand = jnp.full(v_pad, INT_MAX, jnp.int32).at[root].set(0)
-        mu = deg.sum(dtype=jnp.int32) - gl_degext[root]
+        mu = deg.sum(dtype=jnp.int32) - g.deg_ext[root]
         return dict(visited=visited, frontier=visited, pcand=pcand,
                     lcand=lcand, cur=jnp.int32(0), bu=jnp.bool_(False),
                     bu_steps=jnp.int32(0), mu=mu, nf=jnp.int32(1),
@@ -538,22 +568,23 @@ def make_hybrid_stepper(pg: PartitionedGraph, hcfg: HybridConfig,
                                         row_gid, visited, frontier))
         return nxt[None], pc[None]
 
-    shm = shard_map_compat(_compute, mesh=mesh,
-                           in_specs=(P(ax), P(ax), P(ax), P(ax), P(), P(),
-                                     P()),
-                           out_specs=(P(ax), P(ax)))
+    shm = jax.shard_map(_compute, mesh=mesh,
+                        in_specs=(P(ax), P(ax), P(ax), P(ax), P(), P(), P()),
+                        out_specs=(P(ax), P(ax)), check_vma=False)
 
     @jax.jit
-    def compute_fn(state):
-        bu, bu_steps = _decide(hcfg, cfg, v_pad, pg.total_directed_edges,
+    def compute_prog(g: HybridGraph, state):
+        bu, bu_steps = _decide(hcfg, cfg, v_pad, shapes.e_total,
                                state["nf"], state["mf_dec"], state["bu"],
                                state["bu_steps"], state["mu"])
-        nxt_stack, pc_stack = shm(gl_indptr, gl_indices, gl_rowgid, ell,
+        nxt_stack, pc_stack = shm(g.indptr, g.indices, g.row_gid, g.ell,
                                   state["visited"], state["frontier"], bu)
         return nxt_stack, pc_stack, bu, bu_steps
 
     @jax.jit
-    def exchange_fn(state, nxt_stack, pc_stack, bu, bu_steps):
+    def exchange_prog(g: HybridGraph, state, nxt_stack, pc_stack, bu,
+                      bu_steps):
+        deg = g.deg_ext[:-1]
         merged = (jnp.sum(nxt_stack.astype(jnp.int32), axis=0) > 0)
         newly = jnp.where(state["visited"] > 0, 0, merged).astype(jnp.uint8)
         pcand = jnp.where(newly[None] > 0,
@@ -573,7 +604,10 @@ def make_hybrid_stepper(pg: PartitionedGraph, hcfg: HybridConfig,
     def finalize_fn(state):
         return jnp.min(state["pcand"], axis=0), state["lcand"]
 
-    return init_fn, compute_fn, exchange_fn, finalize_fn, make_root_mapper(plan)
+    return (functools.partial(init_prog, graph),
+            functools.partial(compute_prog, graph),
+            functools.partial(exchange_prog, graph), finalize_fn,
+            make_root_mapper(plan))
 
 
 def hybrid_bfs_instrumented(pg: PartitionedGraph, root_orig: int,

@@ -19,7 +19,7 @@ Three backends, auto-selected from partition count and available devices
   hub/tail split (`BFSConfig.hub_split`) specialize scalar and batched
   traversal at once.
 * ``sharded`` — the paper's partitioned BSP search under `shard_map`
-  (`repro.core.hybrid_bfs.make_hybrid_search`), pipelined over roots: all
+  (`repro.core.hybrid_bfs.hybrid_search_program`), pipelined over roots: all
   queries are dispatched asynchronously against one cached executable and
   the host blocks once at the end.
 * ``stepper`` — instrumented per-level python loop (single-partition or
@@ -33,6 +33,7 @@ shape) on the owning `GraphSession` — repeated queries are pure cache hits
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, Optional, Sequence, Union
 
@@ -43,8 +44,9 @@ import numpy as np
 from repro.core import bfs as B
 from repro.core.bfs import BFSConfig
 from repro.core.graph import Graph
-from repro.core.hybrid_bfs import (HybridConfig, finalize_hybrid,
-                                   make_hybrid_search, make_hybrid_stepper)
+from repro.core.hybrid_bfs import (HybridConfig, HybridShapes,
+                                   finalize_hybrid, hybrid_search_program,
+                                   make_hybrid_stepper, make_root_mapper)
 from repro.engine.level_loop import (BSPStepBackend, CohortBatchBackend,
                                      LevelDriver, QueryCancelled,
                                      QueryControl, QueryDeadlineExceeded,
@@ -58,8 +60,8 @@ BACKENDS = ("fused", "sharded", "stepper")
 # Auto-selection: below this many directed edges a single fused program beats
 # the BSP machinery even when more devices exist (exchange overhead dominates).
 AUTO_SHARD_MIN_EDGES = 1 << 19
-# Cap auto-selected partition counts; more partitions than this has never won
-# on the emulated-device containers this repo targets.
+# Cap auto-selected partition counts at the chips of one host (a v5e host
+# has 4 or 8).
 AUTO_MAX_PARTS = 8
 
 RootsLike = Union[int, np.integer, Sequence[int], np.ndarray]
@@ -292,22 +294,21 @@ class Engine:
         batches of 3/5/7 all share one size-8 executable set
         (`trace_count` proves it).
         """
-        dg = self.session.device_graph()
-        ell = self.session.ell_tiles() if B.kernels_enabled(bcfg) else None
+        graph = self.session.cohort_graph(bcfg)
         init = self.session.executable(
             ("cohort", bcfg, bucket, "init"),
-            lambda: lambda roots, active: B.init_batch(dg, bcfg, roots,
-                                                       active))
+            lambda: lambda g, roots, active: B.init_batch(g.dg, bcfg, roots,
+                                                          active))
         steps = {
-            var: self.session.executable(
+            var: functools.partial(self.session.executable(
                 ("cohort", bcfg, bucket, var),
-                lambda v=var: B.make_batch_step(dg, bcfg, v, ell=ell))
+                lambda v=var: B.make_batch_step(bcfg, v)), graph)
             for var in B.reachable_variants(bcfg)
         }
         scalars = self.session.executable(("cohort", bcfg, bucket, "scalars"),
                                           lambda: B.batch_scalars)
-        return CohortBatchBackend(init, steps, scalars, dg.num_vertices,
-                                  bucket)
+        return CohortBatchBackend(functools.partial(init, graph), steps,
+                                  scalars, graph.dg.num_vertices, bucket)
 
     def _bfs_fused(self, roots_arr, hcfg, batched, control=None,
                    on_level=None) -> TraversalResult:
@@ -388,18 +389,20 @@ class Engine:
         plan, pg = self.session.partitioned(n_parts, strategy, hub)
         pkey = (n_parts, strategy, hub)
         skey = ("sharded", hcfg) + pkey
-        ell = (self.session.hybrid_ell(n_parts, strategy, hub)
-               if B.kernels_enabled(hcfg.bfs) else None)
-        search_fn, root_mapper = self.session.cached(
-            ("hybrid_search", hcfg) + pkey,
-            lambda: make_hybrid_search(
-                pg, hcfg, self.session.mesh_for(n_parts, hcfg.axis_name),
-                ell=ell))
-        # Sharded searches close over a device mesh, so the executable is
-        # only valid under this session's device binding: keep it
+        graph = self.session.hybrid_graph(n_parts, strategy, hub,
+                                          hcfg.axis_name,
+                                          B.kernels_enabled(hcfg.bfs))
+        mesh = self.session.mesh_for(n_parts, hcfg.axis_name)
+        # Sharded searches are compiled for this session's device mesh, so
+        # the executable is only valid under its device binding: keep it
         # session-local and off the persistent store.
-        fn = self.session.executable(skey, lambda: search_fn, persist=False)
-        return skey, fn, root_mapper, plan
+        fn = self.session.executable(
+            skey, lambda: hybrid_search_program(HybridShapes.of(pg), hcfg,
+                                                mesh),
+            persist=False)
+        root_mapper = self.session.cached(("root_mapper",) + pkey,
+                                          lambda: make_root_mapper(plan))
+        return skey, functools.partial(fn, graph), root_mapper, plan
 
     def _bfs_sharded(self, roots_arr, hcfg, n_parts, strategy, hub,
                      batched, control=None) -> TraversalResult:
@@ -500,19 +503,18 @@ class Engine:
         ell = self.session.ell_tiles() if B.kernels_enabled(bcfg) else None
         step = self.session.cached(("stepper_step", bcfg),
                                    lambda: B.make_level_step(dg, bcfg, ell))
-        init = self.session.cached(
-            ("stepper_init",),
-            lambda: jax.jit(lambda r: B.init_state(dg, r)))
+        init = self.session.cached(("stepper_init",), lambda: B.make_init(dg))
         return SingleStepBackend(init, step, dg.num_vertices)
 
     def _stepper_backend_sharded(self, hcfg, n_parts, strategy,
                                  hub) -> BSPStepBackend:
         plan, pg = self.session.partitioned(n_parts, strategy, hub)
-        ell = (self.session.hybrid_ell(n_parts, strategy, hub)
-               if B.kernels_enabled(hcfg.bfs) else None)
+        graph = self.session.hybrid_graph(n_parts, strategy, hub,
+                                          hcfg.axis_name,
+                                          B.kernels_enabled(hcfg.bfs))
         pieces = self.session.cached(
             ("hybrid_stepper", hcfg, n_parts, strategy, hub),
             lambda: make_hybrid_stepper(
                 pg, hcfg, self.session.mesh_for(n_parts, hcfg.axis_name),
-                ell=ell))
+                graph=graph))
         return BSPStepBackend(pieces, plan)

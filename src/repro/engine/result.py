@@ -169,7 +169,7 @@ class TraversalResult:
         return parts
 
     def validate(self, graph, sample: Optional[int] = None) -> "TraversalResult":
-        """Graph500-style parent-tree validation against the python oracle.
+        """Graph500-style parent-tree validation (`ref.validate_tree`).
 
         Checks every root, or `sample` evenly spaced roots when set (large
         batches). Raises AssertionError on any invalid tree; returns self so
@@ -179,7 +179,8 @@ class TraversalResult:
         idx = np.arange(self.batch_size)
         if sample is not None and sample < self.batch_size:
             idx = idx[np.linspace(0, self.batch_size - 1, sample).astype(int)]
+        keys = ref.edge_keys(graph) if idx.size else None
         for b in idx:
-            ref.validate_parents(graph, int(self.roots[b]),
-                                 self.parent[b], self.level[b])
+            ref.validate_tree(graph, int(self.roots[b]), self.parent[b],
+                              self.level[b], keys=keys)
         return self

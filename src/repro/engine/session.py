@@ -67,9 +67,11 @@ from repro.analysis.concurrency import ensure_installed as _ensure_sanitizer
 from repro.analysis.concurrency import make_lock, make_rlock
 from repro.core import ell as ELL
 from repro.core import partition as PT
-from repro.core.bfs import DeviceGraph
+from repro.core.bfs import (BFSConfig, CohortGraph, DeviceGraph, hub_rows,
+                            kernels_enabled)
 from repro.core.graph import Graph
-from repro.core.hybrid_bfs import default_mesh
+from repro.core.hybrid_bfs import (HybridGraph, default_mesh,
+                                   place_hybrid_graph)
 from repro.runtime.artifact_cache import artifact_cache_for
 from repro.runtime.config import RuntimeConfig, get_runtime_config
 from repro.runtime.faults import ensure_installed as _ensure_faults
@@ -85,13 +87,12 @@ class _PlanExecutable:
 
     Resolution order: the owning session's preload pool (filled by the
     background pre-warm), then the disk artifact cache, then trace +
-    AOT-compile (persisting the result). Any failure along the
-    AOT/serialization path falls back to a plain `jax.jit` wrapper — the
-    exact pre-runtime-layer behavior — so persistence can never break a
-    query. The wrapper may be shared across sessions via the plan
-    registry; its internal lock makes the first resolution process-wide
-    exclusive, and trace/load counters always land on the *builder*
-    session.
+    AOT-compile (persisting the result). A failed store keeps the compiled
+    executable in memory, so persistence can never break a query; a failed
+    compile is the query's own error and propagates. The wrapper may be
+    shared across sessions via the plan registry; its internal lock makes
+    the first resolution process-wide exclusive, and trace/load counters
+    always land on the *builder* session.
     """
 
     __slots__ = ("_key", "_build", "_static", "_session", "_fp", "_lock",
@@ -151,13 +152,11 @@ class _PlanExecutable:
 
         jitted = jax.jit(counted, static_argnums=self._static)
         cache = sess._artifacts
-        if (self._fp is None or self._static or cache is None
-                or not cache.aot):
+        if self._fp is None or self._static or cache is None:
             return jitted, "traced"
-        try:
-            compiled = jitted.lower(*args).compile()
-        except Exception:  # noqa: BLE001 — AOT unsupported here: plain jit
-            return jitted, "traced"
+        # A compile error propagates: it is the query's error, not a reason
+        # to retry the same program through plain jit.
+        compiled = jitted.lower(*args).compile()
         meta = dict(graph_hash=sess.graph_fingerprint,
                     key=canonical_plan_key(key),
                     **environment_fingerprint())
@@ -227,7 +226,7 @@ class GraphSession:
         self._prewarm_stop = threading.Event()
         _ensure_faults(self.runtime)     # REPRO_FAULTS chaos schedule, if any
         do_prewarm = (self.runtime.prewarm if prewarm is None else prewarm)
-        if do_prewarm and self._artifacts is not None and self._artifacts.aot:
+        if do_prewarm and self._artifacts is not None:
             self._start_prewarm()
 
     # ------------------------------------------------------- preprocessing --
@@ -282,6 +281,32 @@ class GraphSession:
         return self.cached(key, lambda: ELL.build_hybrid_ell(pg, base=base,
                                                              growth=growth))
 
+    def cohort_graph(self, cfg: BFSConfig) -> CohortGraph:
+        """The graph-side arguments of `cfg`'s cohort executables: the CSR,
+        plus the ELL tiles (kernel path) and hub row list (hub split)."""
+        ell = self.ell_tiles() if kernels_enabled(cfg) else None
+        hub = (self.cached(("hub_rows", cfg.hub_deg),
+                           lambda: hub_rows(self.graph.degrees, cfg.hub_deg))
+               if cfg.hub_split else None)
+        return CohortGraph(self.device_graph(), ell, hub)
+
+    def hybrid_graph(self, n_parts: int, strategy: Optional[str] = None,
+                     hub_edge_fraction: Optional[float] = None,
+                     axis_name: str = "part",
+                     kernels: bool = False) -> HybridGraph:
+        """A partitioning committed to its mesh, placed once: every
+        sharded executable and stepper over it takes these arrays as
+        arguments."""
+        strategy = strategy or self.default_strategy
+        hub = (self.default_hub_edge_fraction
+               if hub_edge_fraction is None else hub_edge_fraction)
+        _plan, pg = self.partitioned(n_parts, strategy, hub)
+        return self.cached(
+            ("hybrid_graph", n_parts, strategy, hub, axis_name, kernels),
+            lambda: place_hybrid_graph(
+                pg, self.mesh_for(n_parts, axis_name), axis_name,
+                self.hybrid_ell(n_parts, strategy, hub) if kernels else ()))
+
     def mesh_for(self, n_parts: int, axis_name: str = "part"):
         if self._mesh is not None:
             if self._mesh.devices.size != n_parts:
@@ -330,8 +355,8 @@ class GraphSession:
         their sum — the "this session did first-time work" ledger).
 
         `persist=False` keeps a plan session-local and off disk — the
-        sharded backend's executables close over a device mesh, so they are
-        only valid for the session's own device binding.
+        sharded backend's executables are compiled for a device mesh, so
+        they are only valid for the session's own device binding.
         """
         fn = self._executables.get(key)
         if fn is not None:
@@ -369,17 +394,7 @@ class GraphSession:
                 break
         if cfg is None:
             return
-        # Resolve the kernel backend against *this session's* runtime (the
-        # process-global resolution in core.bfs.kernels_enabled would ignore
-        # a session-private RuntimeConfig).
-        if cfg.backend_kernels is None:
-            mode = self.runtime.kernel_backend
-            enabled = (True if mode == "on" else
-                       False if mode == "off" else
-                       jax.default_backend() == "tpu")
-        else:
-            enabled = cfg.backend_kernels
-        if not enabled:
+        if not kernels_enabled(cfg, self.runtime):
             return
         from repro.analysis.kernel_contracts import (GraphShape,
                                                      contract_report)

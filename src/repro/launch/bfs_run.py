@@ -41,7 +41,7 @@ def sample_roots(g, roots: int, seed: int = 0) -> np.ndarray:
     return rng.choice(candidates, size=k, replace=False)
 
 
-def run(scale: int, nparts: int, strategy: str, roots: int = 8,
+def run(scale: int, nparts: int, strategy: str = "specialized", roots: int = 8,
         heuristic: str = "paper", edgefactor: int = 16, seed: int = 0,
         validate: bool = True, graph=None, cache_dir=None):
     from repro.core import graph as G
@@ -88,6 +88,8 @@ def main(argv=None):
                     help="persistent compiled-executable cache directory "
                          "(default: REPRO_CACHE_DIR if set, else disabled)")
     args = ap.parse_args(argv)
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     res = run(args.scale, args.nparts, args.strategy, args.roots,
               args.heuristic, args.edgefactor, validate=not args.no_validate,
               cache_dir=args.cache_dir)

@@ -545,6 +545,9 @@ def run_restart_probe(cache_dir: str, *, scale: int = 10,
     `warm_traces == 0` is the zero-retrace proof, and
     `warm_start_s < cold_start_s` the payoff. Pass a fresh directory for a
     true cold phase; a pre-populated one just makes both phases warm.
+
+    Call it from a process that has not initialised a JAX backend: each
+    child needs the device, and a parent that has touched it holds it.
     """
     import json
     import os
@@ -611,14 +614,27 @@ def main(argv=None):
                     help="persistent compiled-executable cache directory "
                          "(default: REPRO_CACHE_DIR if set, else disabled)")
     ap.add_argument("--restart-probe", action="store_true",
-                    help="after the load, measure cold-vs-warm restart via "
+                    help="before the load, measure cold-vs-warm restart via "
                          "two child processes sharing the cache dir "
                          "(requires --cache-dir or REPRO_CACHE_DIR)")
     args = ap.parse_args(argv)
 
-    from repro.runtime import configure, get_runtime_config
+    from repro.runtime import (configure, enable_compile_cache,
+                               get_runtime_config)
+    enable_compile_cache()
     if args.cache_dir is not None:
         configure(cache_dir=args.cache_dir)
+    restart = None
+    if args.restart_probe:
+        # First, while this process holds no JAX backend: the probe's
+        # children each need the device, which a parent that has run a
+        # query would hold.
+        cache_dir = get_runtime_config().cache_dir
+        if cache_dir is None:
+            ap.error("--restart-probe needs --cache-dir (or REPRO_CACHE_DIR)")
+        restart = run_restart_probe(cache_dir, scale=min(args.scale, 10),
+                                    edgefactor=args.edgefactor,
+                                    seed=args.seed)
     server, graphs = build_server(
         args.graphs, args.scale, edgefactor=args.edgefactor, seed=args.seed,
         max_queue_depth=args.queue_depth,
@@ -641,14 +657,6 @@ def main(argv=None):
                                 edgefactor=min(args.edgefactor, 8),
                                 seed=args.seed)
         stats["chaos_probe"] = chaos
-    restart = None
-    if args.restart_probe:
-        cache_dir = get_runtime_config().cache_dir
-        if cache_dir is None:
-            ap.error("--restart-probe needs --cache-dir (or REPRO_CACHE_DIR)")
-        restart = run_restart_probe(cache_dir, scale=min(args.scale, 10),
-                                    edgefactor=args.edgefactor,
-                                    seed=args.seed)
     print(f"[serve] {args.graphs} session(s) scale={args.scale} | "
           f"{m['clients']} clients x {args.queries} queries "
           f"(batch {args.batch}): {m['qps']:.1f} QPS, "

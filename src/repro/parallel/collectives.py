@@ -14,19 +14,10 @@ import jax.numpy as jnp
 
 
 def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """`shard_map` across JAX versions.
-
-    Newer JAX exposes `jax.shard_map` (replication check flag `check_vma`);
-    older releases only have `jax.experimental.shard_map.shard_map`
-    (`check_rep`). The replication check is disabled in both: the BFS/MoE
-    bodies use collectives whose replication the checker cannot infer.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """`jax.shard_map` with the replication check off: the MoE body uses
+    collectives whose replication the checker cannot infer."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def or_allreduce_flags(flags: jax.Array, axis_name: str) -> jax.Array:

@@ -8,7 +8,9 @@ compiles:
 * `config`    — `RuntimeConfig`, the single validated object folding the
   scattered env/device flags (kernel backend, interpret mode, cache dir,
   eviction cap, plan sharing, device count) with explicit-arg > env >
-  default precedence, plus the `launch_env()` XLA/tcmalloc launch hygiene.
+  default precedence, plus the `launch_env()` XLA/tcmalloc launch hygiene
+  and `enable_compile_cache()`, which places JAX's persistent compilation
+  cache for the entry points.
 * `fingerprint` — canonical content fingerprints: graph CSR hash, the
   jax/backend environment, and the full plan fingerprint an executable is
   keyed by on disk.
@@ -29,8 +31,9 @@ from disk on attach (background thread, observable progress).
 """
 from repro.runtime.artifact_cache import ArtifactCache, artifact_cache_for
 from repro.runtime.config import (RuntimeConfig, configure,
-                                  get_runtime_config, launch_env,
-                                  reset_runtime_config, runtime_scope)
+                                  enable_compile_cache, get_runtime_config,
+                                  launch_env, reset_runtime_config,
+                                  runtime_scope)
 from repro.runtime.faults import (DevicePressure, FaultInjected,
                                   FaultInjector, FaultSpec, fault_point,
                                   fault_scope, install_faults,
@@ -41,7 +44,8 @@ from repro.runtime.plan_registry import (registry_reset, registry_size,
                                          reset_process_caches)
 
 __all__ = [
-    "RuntimeConfig", "configure", "get_runtime_config", "launch_env",
+    "RuntimeConfig", "configure", "enable_compile_cache",
+    "get_runtime_config", "launch_env",
     "reset_runtime_config", "runtime_scope",
     "ArtifactCache", "artifact_cache_for",
     "DevicePressure", "FaultInjected", "FaultInjector", "FaultSpec",

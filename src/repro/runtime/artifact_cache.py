@@ -32,12 +32,9 @@ Guarantees:
   cumulative load and store seconds, and per-entry hit/load-time counters
   (`stats()`; `BFSServer.stats()` surfaces them per session).
 
-AOT serialization is probed once per process: where
-`jax.experimental.serialize_executable` is unavailable or broken on the
-backend, the cache degrades to enabling JAX's own persistent compilation
-cache in `<cache_dir>/xla` (`jax.config.jax_compilation_cache_dir`), which
-caches at the XLA level (retraces still happen, compiles do not) — slower
-warm-up than executable import, but still bounded cold-start.
+JAX's own persistent compilation cache (XLA level: retraces still happen,
+compiles do not) is separate: `repro.runtime.config.enable_compile_cache`
+places it; this module never touches it.
 """
 from __future__ import annotations
 
@@ -52,48 +49,6 @@ from repro.runtime.faults import fault_point
 PLANS_SUBDIR = "plans"
 ENTRY_SUFFIX = ".exe"
 _TMP_PREFIX = ".tmp-"
-
-_aot_probe_lock = threading.Lock()
-_aot_available: Optional[bool] = None
-
-
-def aot_serialization_available() -> bool:
-    """True when `jax.experimental.serialize_executable` import works."""
-    global _aot_available
-    if _aot_available is None:
-        with _aot_probe_lock:
-            if _aot_available is None:
-                try:
-                    from jax.experimental import serialize_executable  # noqa: F401
-                    _aot_available = True
-                except Exception:  # noqa: BLE001 — any failure means fallback
-                    _aot_available = False
-    return _aot_available
-
-
-def enable_xla_fallback_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache into `<cache_dir>/xla`.
-
-    The fallback when executable export is unavailable: XLA compilations
-    (not traces) persist across processes. Returns False when this jax
-    build rejects the config (fallback unavailable too — cache disabled).
-    """
-    import jax
-    path = os.path.join(cache_dir, "xla")
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # Cache everything: the cohort executables are small and the whole
-        # point is warm restarts, not saving disk on big entries only.
-        for flag, val in (("jax_persistent_cache_min_entry_size_bytes", 0),
-                          ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-            try:
-                jax.config.update(flag, val)
-            except Exception:  # noqa: BLE001 — older jax: flag absent is fine
-                pass
-        return True
-    except Exception:  # noqa: BLE001
-        return False
 
 
 class ArtifactCache:
@@ -110,11 +65,7 @@ class ArtifactCache:
         self._load_s = 0.0
         self._store_s = 0.0
         self._entries: dict = {}     # fingerprint -> dict(hits, load_s, ...)
-        self.aot = aot_serialization_available()
-        self.fallback_active = False
         os.makedirs(self.plans_dir, exist_ok=True)
-        if not self.aot:
-            self.fallback_active = enable_xla_fallback_cache(self.root)
 
     # -------------------------------------------------------------- paths --
 
@@ -177,8 +128,6 @@ class ArtifactCache:
         export, unpicklable pytree, disk full) count as `store_errors` and
         return False — the caller keeps its in-memory executable either way.
         """
-        if not self.aot:
-            return False
         t0 = time.perf_counter()
         tmp = None
         try:
@@ -221,7 +170,7 @@ class ArtifactCache:
         """
         path = self._path(fingerprint)
         t0 = time.perf_counter()
-        if not (self.aot and os.path.exists(path)):
+        if not os.path.exists(path):
             self._count(fingerprint, misses=1)
             return None
         try:
@@ -318,8 +267,7 @@ class ArtifactCache:
             load_s, store_s = self._load_s, self._store_s
         requests = counts["hits"] + counts["misses"]
         return dict(
-            dir=self.root, aot=self.aot, fallback_active=self.fallback_active,
-            entries=len(self), bytes=self.total_bytes(),
+            dir=self.root, entries=len(self), bytes=self.total_bytes(),
             max_bytes=self.max_bytes,
             hit_rate=counts["hits"] / requests if requests else 0.0,
             load_s=load_s, store_s=store_s, per_entry=per_entry, **counts)

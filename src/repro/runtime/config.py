@@ -17,10 +17,12 @@ REPRO_CACHE_MAX_BYTES  cache eviction cap; int bytes or '512MB'/'2GB'
 REPRO_PREWARM          '1'/'0': background pre-warm on `GraphSession` attach
 REPRO_PREWARM_LIMIT    max executables one pre-warm pass deserializes
 REPRO_SHARE_PLANS      '1'/'0': in-process cross-session plan sharing
-REPRO_KERNELS          'auto' | 'on' | 'off': Pallas kernel path when
-                       `BFSConfig.backend_kernels` is None (auto = TPU only)
+REPRO_KERNELS          'on' | 'off' (default off): Pallas kernel path when
+                       `BFSConfig.backend_kernels` is None (off = the XLA
+                       step: Mosaic refuses the kernels for TPU)
 REPRO_INTERPRET        'auto' | 'on' | 'off': Pallas interpret mode when a
-                       kernel call leaves it unset (auto = off-TPU only)
+                       kernel call leaves it unset (auto = interpret off-TPU,
+                       Mosaic lowering on TPU)
 REPRO_DEVICE_COUNT     fake host device count `launch_env()` bakes into
                        XLA_FLAGS (emulated-mesh runs; ignored when unset)
 REPRO_FAULTS           fault-injection schedule (see `repro.runtime.faults`;
@@ -56,6 +58,7 @@ from typing import Optional
 from repro.analysis.vmem import DEFAULT_VMEM_BUDGET
 
 _TRISTATE = ("auto", "on", "off")
+_ONOFF = ("on", "off")
 
 # SNIPPETS §2-3 launch hygiene: the conventional tcmalloc path on the
 # TPU-VM/linux images this repo targets, and the matching allocator knobs.
@@ -94,15 +97,15 @@ def _parse_bool(text: str, *, name: str) -> bool:
     raise ValueError(f"{name}: cannot parse boolean {text!r}")
 
 
-def _parse_tristate(text: str, *, name: str) -> str:
+def _parse_tristate(text: str, *, name: str, allowed=_TRISTATE) -> str:
     s = str(text).strip().lower()
-    if s in _TRISTATE:
-        return s
     if s in ("1", "true", "yes"):
-        return "on"
-    if s in ("0", "false", "no"):
-        return "off"
-    raise ValueError(f"{name}: want one of {_TRISTATE}, got {text!r}")
+        s = "on"
+    elif s in ("0", "false", "no"):
+        s = "off"
+    if s in allowed:
+        return s
+    raise ValueError(f"{name}: want one of {allowed}, got {text!r}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,7 +124,7 @@ class RuntimeConfig:
     # -- in-process plan sharing ---------------------------------------------
     share_plans: bool = True                 # content-hash cross-session cache
     # -- kernel / device selection -------------------------------------------
-    kernel_backend: str = "auto"             # BFSConfig.backend_kernels=None
+    kernel_backend: str = "off"              # BFSConfig.backend_kernels=None
     interpret: str = "auto"                  # Pallas interpret when unset
     device_count: Optional[int] = None       # fake host devices (launch_env)
     # -- launch hygiene (SNIPPETS §2-3) --------------------------------------
@@ -139,8 +142,8 @@ class RuntimeConfig:
         if self.vmem_budget_bytes <= 0:
             raise ValueError(
                 f"vmem_budget_bytes must be > 0, got {self.vmem_budget_bytes}")
-        if self.kernel_backend not in _TRISTATE:
-            raise ValueError(f"kernel_backend: want one of {_TRISTATE}, "
+        if self.kernel_backend not in _ONOFF:
+            raise ValueError(f"kernel_backend: want one of {_ONOFF}, "
                              f"got {self.kernel_backend!r}")
         if self.interpret not in _TRISTATE:
             raise ValueError(f"interpret: want one of {_TRISTATE}, "
@@ -190,8 +193,8 @@ class RuntimeConfig:
             values["share_plans"] = _parse_bool(env["REPRO_SHARE_PLANS"],
                                                 name="REPRO_SHARE_PLANS")
         if "REPRO_KERNELS" in env:
-            values["kernel_backend"] = _parse_tristate(env["REPRO_KERNELS"],
-                                                       name="REPRO_KERNELS")
+            values["kernel_backend"] = _parse_tristate(
+                env["REPRO_KERNELS"], name="REPRO_KERNELS", allowed=_ONOFF)
         if "REPRO_INTERPRET" in env:
             values["interpret"] = _parse_tristate(env["REPRO_INTERPRET"],
                                                   name="REPRO_INTERPRET")
@@ -294,6 +297,30 @@ def runtime_scope(**explicit):
     finally:
         with _lock:
             _current = prev
+
+
+# The checkout root (src/repro/runtime/config.py -> four levels up).
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself and this
+    sets nothing, so whoever launches the process places the cache.
+    Otherwise the cache goes to the fixed `<checkout>/.jax_cache`: a cache
+    directory is part of what it is keyed by, so one that moved between
+    runs would never hit. Entry points call this before their first
+    compile (`bfs_run`, `bfs_serve`, `chip_smoke.py`, the benchmarks).
+    """
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+    path = os.path.join(CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def launch_env(**explicit) -> dict:

@@ -15,8 +15,13 @@ for _p in (SRC, REPO):
 
 
 def run_in_devices(code: str, n_devices: int, timeout: int = 420):
-    """Run python `code` in a subprocess with N fake host devices."""
+    """Run python `code` in a subprocess with N emulated CPU devices.
+
+    A rehearsal of a multi-device path on the CPU backend, never a chip run:
+    the child is pinned to `JAX_PLATFORMS=cpu`, so it cannot contend for an
+    accelerator the parent process may hold."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     res = subprocess.run([sys.executable, "-c", code], env=env,

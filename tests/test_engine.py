@@ -267,3 +267,27 @@ print("ENGINE_SHARDED_OK")
 def test_engine_sharded_4dev():
     out = run_in_devices(SHARDED_CODE, 4, timeout=420)
     assert "ENGINE_SHARDED_OK" in out
+
+
+def _cohort_step_hlo_chars(scale: int) -> dict:
+    """HLO text length of each cohort executable the engine dispatches."""
+    import jax.numpy as jnp
+    g = G.rmat(scale, seed=1)
+    backend = Engine(g)._cohort_backend(BFSConfig(), 8)
+    roots = jnp.asarray(np.arange(8), jnp.int32)
+    state = backend.init((roots, jnp.ones(8, bool)))
+    sizes = {}
+    for variant, step in backend._steps.items():
+        step(state)                       # resolve the plan executable
+        sizes[variant] = len(step.func._fn.lower(*step.args, state).as_text())
+    return sizes
+
+
+def test_cohort_steps_take_the_graph_as_an_argument():
+    """No cohort executable embeds the graph: from scale 10 to 14 the edge
+    count grows 16x, and a closed-over CSR would grow the HLO with it
+    (megabytes of constants); as arguments only shape digits change."""
+    small, large = _cohort_step_hlo_chars(10), _cohort_step_hlo_chars(14)
+    for variant in small:
+        assert large[variant] < 1.05 * small[variant] + 2000, (
+            variant, small[variant], large[variant])

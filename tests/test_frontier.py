@@ -1,10 +1,7 @@
 """Bitmap frontier ops: unit + property tests."""
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:      # run properties on a fixed seeded sample
-    from hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import frontier as fr
 

@@ -1,10 +1,7 @@
 """Graph substrate unit + property tests."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:      # run properties on a fixed seeded sample
-    from hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import graph as G
 

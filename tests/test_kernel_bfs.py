@@ -59,9 +59,17 @@ def test_stepper_kernel_equivalence(small_graph):
 
 
 def test_backend_kernels_auto_resolution():
-    import jax
-    expect = jax.default_backend() == "tpu"
-    assert kernels_enabled(BFSConfig()) == expect
+    # unset runs the XLA step on every backend (Mosaic refuses the kernels
+    # for TPU, tests/test_tpu_compile.py); the kernels stay opt-in, and a
+    # session-private runtime decides exactly as the process one does
+    from repro.runtime import RuntimeConfig, runtime_scope
+    assert kernels_enabled(BFSConfig()) is False
+    with runtime_scope(kernel_backend="on"):
+        assert kernels_enabled(BFSConfig()) is True
+        assert kernels_enabled(BFSConfig(),
+                               RuntimeConfig(kernel_backend="off")) is False
+    assert kernels_enabled(BFSConfig(),
+                           RuntimeConfig(kernel_backend="on")) is True
     assert kernels_enabled(BFSConfig(backend_kernels=True)) is True
     assert kernels_enabled(BFSConfig(backend_kernels=False)) is False
 

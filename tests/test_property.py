@@ -1,9 +1,6 @@
 """System-level property tests (hypothesis) for core invariants."""
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:      # run properties on a fixed seeded sample
-    from hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import graph as G, ref
 from repro.core.bfs import BFSConfig, bfs

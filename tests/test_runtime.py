@@ -53,6 +53,7 @@ def test_config_parsing_and_validation():
         _parse_size("lots", name="x")
     for env, match in (
             ({"REPRO_KERNELS": "maybe"}, "REPRO_KERNELS"),
+            ({"REPRO_KERNELS": "auto"}, "REPRO_KERNELS"),
             ({"REPRO_PREWARM": "sometimes"}, "REPRO_PREWARM")):
         with pytest.raises(ValueError, match=match):
             RuntimeConfig.resolve(env)
@@ -61,6 +62,7 @@ def test_config_parsing_and_validation():
     with pytest.raises(ValueError, match="kernel_backend"):
         RuntimeConfig(kernel_backend="gpuish")
     assert RuntimeConfig.resolve({"REPRO_KERNELS": "1"}).kernel_backend == "on"
+    assert RuntimeConfig.resolve({}).kernel_backend == "off"
 
 
 def test_launch_env_shape():
@@ -74,6 +76,28 @@ def test_launch_env_shape():
 
 
 # ---------------------------------------------------------- fingerprints --
+
+def test_compile_cache_honours_env_else_fixed_checkout_path(monkeypatch):
+    """`enable_compile_cache` leaves a placed JAX_COMPILATION_CACHE_DIR to
+    JAX; without one it points the cache at the checkout's fixed
+    `.jax_cache` — the same path on every call, so runs share it."""
+    import jax
+    from repro.runtime import enable_compile_cache
+    from repro.runtime.config import CHECKOUT
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert enable_compile_cache() == "/placed/elsewhere"
+        assert jax.config.jax_compilation_cache_dir is None   # set nothing
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        first, second = enable_compile_cache(), enable_compile_cache()
+        assert first == second == os.path.join(CHECKOUT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+        assert os.path.isfile(os.path.join(CHECKOUT, "chip_smoke.py"))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
 
 def test_graph_fingerprint_content_not_identity():
     a = G.rmat(8, seed=5)
