@@ -56,6 +56,7 @@ from repro.core import ell as ELL
 from repro.core import frontier as fr
 from repro.core.graph import Graph
 from repro.kernels import ops as K
+from repro.runtime.spans import span
 
 INT_MAX = np.iinfo(np.int32).max
 
@@ -1027,11 +1028,14 @@ def finalize(st: BFSState) -> tuple[np.ndarray, np.ndarray]:
     """Sentinels -> Graph500 conventions (-1 for unreached).
 
     Works on a `BFSState` ([V] arrays) or a `BatchState` ([B, V] arrays)."""
-    parent = np.asarray(st.parent)
-    level = np.asarray(st.level)
-    parent = np.where(parent == INT_MAX, -1, parent)
-    level = np.where(level == INT_MAX, -1, level)
-    return parent.astype(np.int32), level.astype(np.int32)
+    with span("repro.result.transfer",
+              bytes=int(st.parent.nbytes + st.level.nbytes)):
+        parent = np.asarray(st.parent)
+        level = np.asarray(st.level)
+    with span("repro.result.convert"):
+        parent = np.where(parent == INT_MAX, -1, parent)
+        level = np.where(level == INT_MAX, -1, level)
+        return parent.astype(np.int32), level.astype(np.int32)
 
 
 def bfs(g: Graph | DeviceGraph, root: int,

@@ -21,6 +21,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro.runtime.spans import span
+
 # Graph500 reference R-MAT parameters.
 RMAT_A, RMAT_B, RMAT_C = 0.57, 0.19, 0.19
 EDGEFACTOR = 16
@@ -88,21 +90,27 @@ def from_edges(src: np.ndarray, dst: np.ndarray, num_vertices: int,
       sort_by_degree: order each adjacency list by descending neighbour degree
         (paper §3.4). Disable for the "naive" baseline in Table 1.
     """
-    src, dst = _dedupe_edges(np.asarray(src), np.asarray(dst))
-    if symmetrize:
-        src, dst = np.concatenate([src, dst]), np.concatenate([dst, src])
-    src = src.astype(np.int64)
-    dst = dst.astype(np.int32)
-    degrees = np.bincount(src, minlength=num_vertices).astype(np.int32)
-    indptr = np.zeros(num_vertices + 1, dtype=np.int64)
-    np.cumsum(degrees, out=indptr[1:])
-    # One stable sort by row (and, for the paper's ordering, by descending
-    # neighbour degree within a row); ties keep their edge-list order.
-    key = _row_major_key(src, degrees[dst] if sort_by_degree else None,
-                         degrees)
-    indices = dst[np.argsort(key, kind="stable")]
+    src, dst = np.asarray(src), np.asarray(dst)
+    with span("repro.ingest.dedupe", edges_in=len(src)) as dedupe:
+        src, dst = _dedupe_edges(src, dst)
+        dedupe.attrs["edges_out"] = len(src)
+    with span("repro.ingest.sort"):
+        if symmetrize:
+            src, dst = np.concatenate([src, dst]), np.concatenate([dst, src])
+        src = src.astype(np.int64)
+        dst = dst.astype(np.int32)
+        degrees = np.bincount(src, minlength=num_vertices).astype(np.int32)
+        indptr = np.zeros(num_vertices + 1, dtype=np.int64)
+        np.cumsum(degrees, out=indptr[1:])
+        # One stable sort by row (and, for the paper's ordering, by
+        # descending neighbour degree within a row); ties keep their
+        # edge-list order.
+        key = _row_major_key(src, degrees[dst] if sort_by_degree else None,
+                             degrees)
+        indices = dst[np.argsort(key, kind="stable")]
     g = Graph(num_vertices, indptr, indices, degrees)
-    g.validate()
+    with span("repro.ingest.validate"):
+        g.validate()
     return g
 
 

@@ -54,6 +54,7 @@ from repro.engine.level_loop import (BSPStepBackend, CohortBatchBackend,
 from repro.engine.result import TraversalResult, edges_traversed_from_levels
 from repro.engine.session import GraphSession
 from repro.runtime.faults import fault_point
+from repro.runtime.spans import span
 
 BACKENDS = ("fused", "sharded", "stepper")
 
@@ -174,12 +175,14 @@ class Engine:
         session-default partition knobs so "default" and an explicitly
         passed default coincide.
         """
-        hcfg = self._normalize_cfg(cfg)
-        backend, n_parts = self._resolve(backend, n_parts)
-        strategy = strategy or self.session.default_strategy
-        if hub_edge_fraction is None:
-            hub_edge_fraction = self.session.default_hub_edge_fraction
-        return QueryPlan(backend, n_parts, hcfg, strategy, hub_edge_fraction)
+        with span("repro.plan"):
+            hcfg = self._normalize_cfg(cfg)
+            backend, n_parts = self._resolve(backend, n_parts)
+            strategy = strategy or self.session.default_strategy
+            if hub_edge_fraction is None:
+                hub_edge_fraction = self.session.default_hub_edge_fraction
+            return QueryPlan(backend, n_parts, hcfg, strategy,
+                             hub_edge_fraction)
 
     def bfs(self, roots: RootsLike, cfg=None, *, backend: str = "auto",
             n_parts: Optional[int] = None, strategy: Optional[str] = None,
@@ -230,6 +233,12 @@ class Engine:
                  on_level: Optional[Callable] = None,
                  control: Optional[QueryControl] = None) -> TraversalResult:
         """Run a query whose knobs were already resolved by `plan()`."""
+        with span("repro.search", backend=plan.backend,
+                  batch=int(np.size(roots))):
+            return self._bfs_plan(roots, plan, batched, validate, on_level,
+                                  control)
+
+    def _bfs_plan(self, roots, plan, batched, validate, on_level, control):
         backend, n_parts = plan.backend, plan.n_parts
         hcfg = plan.hcfg
         if on_level is not None and not (
@@ -263,8 +272,9 @@ class Engine:
         else:
             res = self._bfs_stepper(roots_arr, hcfg, n_parts, plan.strategy,
                                     plan.hub_edge_fraction, on_level, control)
-        res.edges_traversed = edges_traversed_from_levels(self.graph.degrees,
-                                                          res.level)
+        with span("repro.result.assemble"):
+            res.edges_traversed = edges_traversed_from_levels(
+                self.graph.degrees, res.level)
         if validate:
             res.validate(self.graph)
         return res
@@ -377,11 +387,12 @@ class Engine:
                 (jnp.asarray([r], jnp.int32), active1), None, control)
             per_root.append(time.perf_counter() - t0)
             parents.append(parent[0]); levels.append(level[0])
-        per_root = np.asarray(per_root)
-        level = np.stack(levels)
-        return TraversalResult(roots_arr, np.stack(parents), level,
-                               _tree_depth(level), float(per_root.sum()),
-                               per_root, "fused", 1, e_und)
+        with span("repro.result.assemble"):
+            per_root = np.asarray(per_root)
+            level = np.stack(levels)
+            return TraversalResult(roots_arr, np.stack(parents), level,
+                                   _tree_depth(level), float(per_root.sum()),
+                                   per_root, "fused", 1, e_und)
 
     # ------------------------------------------------------- sharded path --
 

@@ -19,6 +19,9 @@ The driver owns everything the four loops duplicated:
   (this is the only such site in the repo);
 * the stats-row schema (level, direction, frontier_size, frontier_edges,
   seconds, compute_s, exchange_s) and the `on_level` streaming hook;
+* the spans of a search's phases (`repro.runtime.spans`): `repro.level.init`,
+  `.step`, `.exchange` (partitioned backends), `.sync` and `.finalize`; the
+  row's times and the `timings` are read off their timestamps;
 * the termination bound, checked *before* stepping: no BFS level can exceed
   `depth_bound` = the TOTAL vertex count minus one (levels 0..V-1 all
   non-empty pigeonholes every vertex into the visited set), so a frontier
@@ -52,7 +55,8 @@ Extended (batched-cohort) protocol, opted into per backend:
   host dict from the most recent sync, so the backend can pick which
   compiled step to dispatch from cohort occupancy without a second device
   round-trip (`CohortBatchBackend` selects its td/bu/mixed executable
-  this way).
+  this way). Such a backend also has `variant(sync)`, the name of the
+  step it will dispatch, which the step's span carries.
 * `row_extra(pre, post)` (optional) merges backend-specific fields into
   the level's stats row — `pre` is the sync entering the step (per-lane
   frontier stats, the directions the step used), `post` the one after it
@@ -70,6 +74,7 @@ import jax.numpy as jnp
 from repro.core import bfs as B
 from repro.core.hybrid_bfs import finalize_hybrid
 from repro.runtime.faults import fault_point
+from repro.runtime.spans import span
 
 
 # ------------------------------------------------------------ cancellation --
@@ -267,8 +272,12 @@ class CohortBatchBackend:
         roots, active = root
         return self._init(roots, active)
 
+    def variant(self, sync) -> str:
+        """The step variant the level after `sync` dispatches."""
+        return self.variant_for(int(sync["td_next"]), int(sync["bu_next"]))
+
     def compute(self, state, sync):
-        variant = self.variant_for(int(sync["td_next"]), int(sync["bu_next"]))
+        variant = self.variant(sync)
         self.dispatched[variant] += 1
         return self._steps[variant](state)
 
@@ -337,22 +346,24 @@ class LevelDriver:
     def __init__(self, backend):
         self.backend = backend
 
-    def _sync(self, state):
+    def _sync(self, state, cur: int):
         """THE per-level host sync — the repo's single `device_get` site.
 
         Loop condition, stats row, direction flag, and the depth bound all
         come from this one read — a four-scalar tuple, or a dict carrying
         the same keys plus backend extras (the batched cohort backend's
         occupancy counts and per-lane vectors); separate `int()`/`bool()`
-        reads would each issue their own device round-trip.
+        reads would each issue their own device round-trip. `cur` is the
+        level the synced state has reached (the span's attribute).
         """
-        # repro-ok: TH001 THE sanctioned per-level sync: exactly one device_get per BFS level
-        host = jax.device_get(self.backend.scalars(state))
-        if not isinstance(host, dict):
-            nf, mf, cur, bu = host
-            host = dict(nf=nf, mf=mf, cur=cur, bu=bu)
-        return (int(host["nf"]), int(host["mf"]), int(host["cur"]),
-                bool(host["bu"]), host)
+        with span("repro.level.sync", level=cur):
+            # repro-ok: TH001 THE sanctioned per-level sync: exactly one device_get per BFS level
+            host = jax.device_get(self.backend.scalars(state))
+            if not isinstance(host, dict):
+                nf, mf, cur, bu = host
+                host = dict(nf=nf, mf=mf, cur=cur, bu=bu)
+            return (int(host["nf"]), int(host["mf"]), int(host["cur"]),
+                    bool(host["bu"]), host)
 
     def run(self, root: int, on_level: Optional[Callable] = None,
             control: Optional[QueryControl] = None):
@@ -368,13 +379,12 @@ class LevelDriver:
         b = self.backend
         needs_sync = getattr(b, "needs_sync", False)
         row_extra = getattr(b, "row_extra", None)
-        t_run = time.perf_counter()
-        state = b.init(root)
-        # repro-ok: TH001 timing fence: init_s must not absorb async dispatch of the first level
-        jax.block_until_ready(state)
-        init_s = time.perf_counter() - t_run
+        with span("repro.level.init") as init:
+            state = b.init(root)
+            # repro-ok: TH001 timing fence: init_s must not absorb async dispatch of the first level
+            jax.block_until_ready(state)
         stats: list = []
-        nf, mf, cur, bu, pre = self._sync(state)
+        nf, mf, cur, bu, pre = self._sync(state, cur=0)
         while nf > 0 and cur < b.depth_bound:
             if control is not None:
                 try:
@@ -391,30 +401,42 @@ class LevelDriver:
             fctx = getattr(b, "fault_ctx", None) or {}
             fault_point("straggler", level=cur, **fctx)
             fault_point("dispatch", level=cur, **fctx)
-            t0 = time.perf_counter()
-            work = b.compute(state, pre) if needs_sync else b.compute(state)
-            # repro-ok: TH001 timing fence: per-level compute_s is a reported paper metric
-            jax.block_until_ready(work)
-            t1 = time.perf_counter()
-            state = b.exchange(state, work)
-            # repro-ok: TH001 timing fence: exchange_s isolates the partition-boundary cost
-            jax.block_until_ready(state)
-            t2 = time.perf_counter()
-            nf2, mf2, cur, bu, post = self._sync(state)
-            row = dict(level=cur, seconds=t2 - t0, compute_s=t1 - t0,
-                       exchange_s=(t2 - t1) if b.has_exchange else 0.0,
+            # A cohort backend names the step it dispatches before it runs;
+            # for the others the direction comes with the sync after it.
+            attrs = dict(level=cur + 1, nf=nf, mf=mf)
+            if needs_sync:
+                attrs["variant"] = b.variant(pre)
+            with span("repro.level.step", **attrs) as step:
+                work = b.compute(state, pre) if needs_sync else b.compute(state)
+                # repro-ok: TH001 timing fence: per-level compute_s is a reported paper metric
+                jax.block_until_ready(work)
+            t_end = step.t1
+            if b.has_exchange:
+                with span("repro.level.exchange", level=cur + 1) as ex:
+                    state = b.exchange(state, work)
+                    # repro-ok: TH001 timing fence: exchange_s isolates the partition-boundary cost
+                    jax.block_until_ready(state)
+                t_end = ex.t1
+            else:
+                state = b.exchange(state, work)
+            nf2, mf2, cur, bu, post = self._sync(state, cur=cur + 1)
+            row = dict(level=cur, seconds=t_end - step.t0,
+                       compute_s=step.t1 - step.t0,
+                       exchange_s=t_end - step.t1,
                        direction="bu" if bu else "td",
                        frontier_size=nf, frontier_edges=mf)
             if row_extra is not None:
                 row.update(row_extra(pre, post))
+            step.attrs.setdefault("variant", row["direction"])
             stats.append(row)
             if on_level:
                 on_level(row)
             nf, mf, pre = nf2, mf2, post
-        t0 = time.perf_counter()
-        parent, level = b.finalize(state)
-        agg_s = time.perf_counter() - t0
-        overhead = (time.perf_counter() - t_run) - init_s - agg_s \
+        with span("repro.level.finalize") as agg:
+            parent, level = b.finalize(state)
+        init_s = init.t1 - init.t0
+        agg_s = agg.t1 - agg.t0
+        overhead = (agg.t1 - init.t0) - init_s - agg_s \
             - sum(r["seconds"] for r in stats)
         return parent, level, stats, dict(init_s=init_s, agg_s=agg_s,
                                           driver_overhead_s=max(overhead, 0.0))

@@ -55,6 +55,7 @@ counters without that session's lock).
 """
 from __future__ import annotations
 
+import re
 import threading
 import time
 import warnings
@@ -80,6 +81,23 @@ from repro.runtime.fingerprint import (canonical_plan_key,
                                        environment_fingerprint,
                                        graph_fingerprint, plan_fingerprint)
 from repro.runtime.plan_registry import registry_get, registry_put
+from repro.runtime.spans import span
+
+
+def plan_name(key) -> str:
+    """The stable name of a plan's executable, from its key: the XLA
+    module is `jit_<name>`, so a device trace says which step ran.
+
+    ("cohort", cfg, 1, "td") -> "cohort_b1_td"; ("sharded", ...) ->
+    "sharded_search"; any other key joins its string parts.
+    """
+    parts = key if isinstance(key, tuple) else (key,)
+    if parts[0] == "cohort":
+        return f"cohort_b{parts[2]}_{parts[3]}"
+    if parts[0] == "sharded":
+        return "sharded_search"
+    words = [re.sub(r"\W", "_", p) for p in parts if isinstance(p, str)]
+    return "_".join(words) or "plan"
 
 
 class _PlanExecutable:
@@ -93,10 +111,15 @@ class _PlanExecutable:
     shared across sessions via the plan registry; its internal lock makes
     the first resolution process-wide exclusive, and trace/load counters
     always land on the *builder* session.
+
+    The first call is the `repro.plan.resolve` span (attributes `key`,
+    the plan's name, and `source`: traced | disk | prewarmed). It holds
+    the resolution and the first dispatch, where a plan that was not
+    compiled ahead of time traces and compiles.
     """
 
     __slots__ = ("_key", "_build", "_static", "_session", "_fp", "_lock",
-                 "_fn", "source", "resolve_s")
+                 "_fn")
 
     def __init__(self, key, build: Callable[[], Callable], static_argnums,
                  session: "GraphSession", fingerprint: Optional[str]):
@@ -107,37 +130,37 @@ class _PlanExecutable:
         self._fp = fingerprint          # None = never persisted to disk
         self._lock = make_lock("plan_exec")
         self._fn: Optional[Callable] = None
-        self.source: Optional[str] = None   # traced | disk | prewarmed
-        self.resolve_s = 0.0
 
     def __call__(self, *args):
         fn = self._fn
         if fn is None:
-            fn = self._resolve(args)
+            return self._resolve(args)
         return fn(*args)
 
-    def _resolve(self, args) -> Callable:
+    def _resolve(self, args):
+        """Resolve the plan and make its first call under the lock."""
         with self._lock:
             if self._fn is not None:
-                return self._fn
-            t0 = time.perf_counter()
-            sess = self._session
-            fn = source = None
-            if self._fp is not None:
-                fn = sess._take_preloaded(self._fp)
-                if fn is not None:
-                    source = "prewarmed"
-                elif sess._artifacts is not None:
-                    fn = sess._artifacts.load(self._fp)
+                return self._fn(*args)
+            with span("repro.plan.resolve", key=plan_name(self._key)) as sp:
+                sess = self._session
+                fn = source = None
+                if self._fp is not None:
+                    fn = sess._take_preloaded(self._fp)
                     if fn is not None:
-                        source = "disk"
-            if fn is None:
-                fn, source = self._trace(args)
-            self._fn = fn
-            self.source = source
-            self.resolve_s = time.perf_counter() - t0
-            sess._note_resolved(self._key, source)
-            return fn
+                        source = "prewarmed"
+                    elif sess._artifacts is not None:
+                        fn = sess._artifacts.load(self._fp)
+                        if fn is not None:
+                            source = "disk"
+                if fn is None:
+                    fn, source = self._trace(args)
+                sp.attrs["source"] = source
+                sess._note_resolved(self._key, source)
+                try:
+                    return fn(*args)
+                finally:
+                    self._fn = fn
 
     def _trace(self, args):
         """Build + jit; AOT-compile and persist when the store is usable."""
@@ -150,6 +173,7 @@ class _PlanExecutable:
             _sess._bump_trace(_key)
             return _raw(*a)
 
+        counted.__name__ = counted.__qualname__ = plan_name(key)
         jitted = jax.jit(counted, static_argnums=self._static)
         cache = sess._artifacts
         if self._fp is None or self._static or cache is None:

@@ -25,17 +25,12 @@ from typing import Any, Optional
 
 _lock = threading.Lock()
 _plans: dict = {}
-_hits = 0
 
 
 def registry_get(key) -> Optional[Any]:
     """The shared executable for `(graph_hash, plan_key)`, if published."""
-    global _hits
     with _lock:
-        fn = _plans.get(key)
-        if fn is not None:
-            _hits += 1
-        return fn
+        return _plans.get(key)
 
 
 def registry_put(key, fn) -> Any:
@@ -49,17 +44,10 @@ def registry_size() -> int:
         return len(_plans)
 
 
-def registry_stats() -> dict:
-    with _lock:
-        return dict(plans=len(_plans), hits=_hits)
-
-
 def registry_reset() -> None:
     """Drop every shared plan (tests / explicit invalidation)."""
-    global _hits
     with _lock:
         _plans.clear()
-        _hits = 0
 
 
 def reset_process_caches() -> None:
