@@ -10,11 +10,13 @@ shapes so the whole search (or one level) is a compiled XLA program:
   over the queue's degree prefix sum — the TPU-native replacement for the
   GPU's per-thread edge binning ("virtual warp" has no TPU analogue; see
   API.md §Kernel-backed traversal).
-* **Bottom-up (pull)**: unvisited vertices are scanned in row chunks; each
-  chunk walks its adjacency in width-`bu_slab` slabs with a while-loop that
-  exits as soon as every row in the chunk found a frontier parent —
-  block-granularity early exit, enabled by the descending-degree adjacency
-  ordering (paper §3.4).
+* **Bottom-up (pull)**: unvisited vertices are queued, and the queue is
+  walked slab by slab over a list of survivors: each slab gathers a few
+  more adjacency slots of every row still on the list (widths 1, 1, 2, 4,
+  ... up to `bu_slab`), and only rows that found no frontier parent and
+  have slots left go on to the next. Gathers follow the slots rows need,
+  which the descending-degree adjacency ordering (paper §3.4) keeps small:
+  a row's first slot is usually a hub already in the frontier.
 * Direction switching implements both the paper's heuristic (static fraction
   of total edges + fixed number of bottom-up rounds, §3.3) and Beamer's
   alpha/beta heuristic.
@@ -70,8 +72,11 @@ class BFSConfig:
     gamma: float = 0.06           # paper: switch down when mf > gamma * E
     fixed_bu_steps: int = 3       # paper: return to top-down after N BU rounds
     td_chunk: int = 4096          # edge slots per top-down chunk
-    bu_chunk: int = 512           # rows per bottom-up chunk
-    bu_slab: int = 32             # neighbour slots per bottom-up slab
+    bu_chunk: int = 512           # rows per bottom-up block of the
+                                  # sharded pull and of the kernel path's
+                                  # contract model (the XLA pull sizes its
+                                  # own blocks)
+    bu_slab: int = 32             # widest bottom-up slab (neighbour slots)
     max_levels: int = 0           # 0 = num_vertices (safe upper bound)
     # Heterogeneous hub/tail dispatch (API.md §Heterogeneous dispatch).
     # When `hub_split` is on, every cohort level is executed as two sides:
@@ -123,10 +128,9 @@ class DeviceGraph:
     """CSR graph as device arrays (+ one-slot padding for queue-fill gathers).
 
     `pull_order` lists the rows a bottom-up pass may scan — every row of
-    nonzero degree, by descending degree — then fill ids (V). Pulling rows
-    in that order groups rows of like degree into one chunk, so a chunk's
-    slab loop is not held open by one wide row among narrow ones, and the
-    zero-degree rows (which can never find a parent) cost no chunk at all.
+    nonzero degree, by descending degree — then fill ids (V). The XLA pull
+    queues rows in that order, so the zero-degree rows (which can never
+    find a parent) never reach its survivor list.
     """
     indptr: jax.Array      # int32[V+1]
     indices: jax.Array     # int32[E]
@@ -253,72 +257,110 @@ def _top_down_step(dg: DeviceGraph, cfg: BFSConfig, frontier, visited, parent,
 
 # --------------------------------------------------------------- bottom-up --
 
-def _bottom_up_step(dg: DeviceGraph, cfg: BFSConfig, frontier, visited,
-                    parent_in, row_mask=None, chunk=None, slab=None):
-    """One pull level: row chunks x adjacency slabs with block early exit.
+# Slab widths of the XLA pull, narrowest first. The widest slab (`bu_slab`,
+# or a side's override) caps them and repeats until no row is left. After
+# each rung a surviving row has used every slot before the next width, so a
+# skewed graph's rows mostly stop at their first slot and a uniform graph's
+# rows pay for a few doublings, not for a full-width slab.
+PULL_LADDER = (1, 1, 2, 4, 8, 16)
+# Adjacency slots one block of a pull slab gathers (rows x width): a
+# width-1 slab walks 32,768 rows at a time, a width-32 one 1,024. On a
+# v5e chip 2^15 and 2^16 pulled a scale-22 graph fastest, 2^13, 2^14 and
+# 2^17 slower.
+PULL_BLOCK_SLOTS = 1 << 15
 
-    Rows are pulled in `dg.pull_order` (nonzero degree, widest first), so
-    a chunk holds rows of like degree. `row_mask` (bool[V] or None)
-    restricts the unvisited scan: the heterogeneous split passes a
-    per-vertex side mask here, plus side-tuned `chunk`/`slab` overrides
-    (defaults: `cfg.bu_chunk`/`cfg.bu_slab`). The per-row first-hit parent
-    is invariant under chunk grouping, row order and slab width (first hit
-    == lowest adjacency slot regardless of how slots are grouped), so any
-    side partition or order of the rows produces bitwise-identical flags
-    and parents to one unsplit pass — they only change how many slab
-    iterations a chunk's widest row can force on its neighbours.
+
+def _bottom_up_step(dg: DeviceGraph, cfg: BFSConfig, frontier, visited,
+                    parent_in, row_mask=None, slab=None):
+    """One pull level, slab-major over a shrinking list of survivors.
+
+    Rows to pull are queued in `dg.pull_order` (nonzero degree, widest
+    first) from the unvisited ones; `row_mask` (bool[V] or None) restricts
+    them further (the heterogeneous split's side mask) and `slab`
+    overrides the widest slab (default `cfg.bu_slab`). Every queued row
+    starts on the survivor list. Slab widths follow `PULL_LADDER`, capped
+    at the widest slab, which then repeats. A slab walks the list in
+    blocks and gathers `width` adjacency slots of each row, from the slot
+    after those the row has used: a row that hits a frontier neighbour
+    takes the first one as parent, and a row with no hit and slots left
+    is compacted to the front of the list for the next slab. Every row on
+    the list has used the same slots, the sum of the widths so far, so
+    the list holds row ids alone.
+
+    A row's parent is the neighbour in its lowest adjacency slot that is
+    in the frontier, however slots are grouped into slabs and rows into
+    blocks, so flags and parents are bitwise those of any other grouping,
+    order or side partition of the rows.
+
+    Returns `(next_flags, parent, counts)`, `counts` int32[2]: the rows
+    queued and the adjacency slots gathered for them (a row's slots past
+    its degree are not counted).
     """
     v = dg.num_vertices
-    r = min(chunk or cfg.bu_chunk, v)
-    w = slab or cfg.bu_slab
+    i32 = jnp.int32
+    e_last = max(dg.num_directed_edges - 1, 0)
+    cap = slab or cfg.bu_slab
+    widths = [w for w in PULL_LADDER if w < cap]
+    # A block read at any offset below the live count stays in bounds.
+    size = v + PULL_BLOCK_SLOTS
     order = dg.pull_order
     order_rows = jnp.minimum(order, v - 1)
     pull = (order < v) & (visited[order_rows] == 0)
     if row_mask is not None:
         pull = pull & row_mask[order_rows]
-    at, m = fr.compact(pull.astype(jnp.uint8))   # positions in pull_order
-    queue = jnp.where(at < v, order[jnp.minimum(at, v - 1)], v)  # fill == v
+    at = jnp.cumsum(pull, dtype=i32) - 1
+    m = at[-1] + 1 if v else i32(0)
+    live = jnp.full(size, v, i32).at[jnp.where(pull, at, size)].set(
+        order, mode="drop")
 
-    def chunk_body(carry):
-        base, next_flags, parent = carry
-        rows = jax.lax.dynamic_slice(queue, (base,), (r,))   # may include fill
-        rows_safe = jnp.minimum(rows, v)          # deg_ext[v] == 0
-        rdeg = dg.deg_ext[rows_safe]
-        rptr = jnp.where(rows < v, dg.indptr[jnp.minimum(rows, v - 1)], 0)
+    def pull_slab(w, used, n, live, pcand, slots):
+        """One slab of width `w` over the `n` rows at the list's front,
+        each of which has used its first `used` slots. The rows that stay
+        are compacted in place to the front; returns their count and the
+        carry."""
+        t = max(PULL_BLOCK_SLOTS // w, 1)        # rows per block
+        col = jnp.arange(w, dtype=i32)
 
-        def slab_cond(sc):
-            s, found, _ = sc
-            return jnp.any(~found & (rdeg > s * w))
-
-        def slab_body(sc):
-            s, found, par = sc
-            col = s * w + jnp.arange(w, dtype=jnp.int32)
-            nidx = rptr[:, None] + col[None, :]
-            nvalid = (col[None, :] < rdeg[:, None]) & ~found[:, None]
-            nidx = jnp.clip(nidx, 0, max(dg.num_directed_edges - 1, 0))
-            nbr = jnp.where(nvalid, dg.indices[nidx], 0)
-            hit = nvalid & (frontier[nbr] > 0)
-            anyhit = jnp.any(hit, axis=1)
+        def block(c):
+            base, kept, live, pcand, slots = c
+            rows = jax.lax.dynamic_slice(live, (base,), (t,))
+            rows = jnp.where(base + jnp.arange(t, dtype=i32) < n, rows, v)
+            left = dg.deg_ext[rows] - used          # <= 0 for fill (v)
+            ok = col[None, :] < left[:, None]
+            nidx = jnp.clip(dg.indptr[rows][:, None] + used + col[None, :],
+                            0, e_last)
+            nbr = jnp.where(ok, dg.indices[nidx], 0)
+            hit = ok & (frontier[nbr] > 0)
+            found = jnp.any(hit, axis=1)
             first = jnp.argmax(hit, axis=1)
-            pcand = nbr[jnp.arange(r), first]
-            par = jnp.where(~found & anyhit, pcand, par)
-            return s + 1, found | anyhit, par
+            par = jnp.sum(jnp.where(col[None, :] == first[:, None], nbr, 0),
+                          axis=1)
+            pcand = pcand.at[jnp.where(found, rows, v)].set(par, mode="drop")
+            keep = ~found & (left > w)
+            dst = kept + jnp.cumsum(keep, dtype=i32) - 1
+            live = live.at[jnp.where(keep, dst, size)].set(rows, mode="drop")
+            slots = slots + jnp.sum(jnp.clip(left, 0, w), dtype=i32)
+            return base + t, kept + jnp.sum(keep, dtype=i32), live, pcand, \
+                slots
 
-        found0 = jnp.zeros(r, bool)
-        par0 = jnp.full(r, INT_MAX, jnp.int32)
-        _, found, par = jax.lax.while_loop(
-            slab_cond, slab_body, (jnp.int32(0), found0, par0))
-        # rows may contain the fill id v -> mode="drop" discards those.
-        next_flags = next_flags.at[rows].max(found.astype(jnp.uint8), mode="drop")
-        parent = parent.at[rows].min(jnp.where(found, par, INT_MAX), mode="drop")
-        return base + r, next_flags, parent
+        _, kept, live, pcand, slots = jax.lax.while_loop(
+            lambda c: c[0] < n, block, (i32(0), i32(0), live, pcand, slots))
+        return kept, live, pcand, slots
 
-    def chunk_cond(carry):
-        return carry[0] < m
+    n, pcand, slots, used = m, jnp.full(v, INT_MAX, i32), i32(0), 0
+    for w in widths:
+        n, live, pcand, slots = pull_slab(w, used, n, live, pcand, slots)
+        used += w
 
-    init = (jnp.int32(0), jnp.zeros(v, jnp.uint8), parent_in)
-    _, next_flags, parent = jax.lax.while_loop(chunk_cond, chunk_body, init)
-    return next_flags, parent
+    def cap_body(c):
+        used, n, live, pcand, slots = c
+        n, live, pcand, slots = pull_slab(cap, used, n, live, pcand, slots)
+        return used + cap, n, live, pcand, slots
+
+    _, _, _, pcand, slots = jax.lax.while_loop(
+        lambda c: c[1] > 0, cap_body, (i32(used), n, live, pcand, slots))
+    next_flags = (pcand != INT_MAX).astype(jnp.uint8)
+    return next_flags, jnp.minimum(parent_in, pcand), jnp.stack([m, slots])
 
 
 # -------------------------------------------------------- kernel-path steps --
@@ -326,8 +368,8 @@ def _bottom_up_step(dg: DeviceGraph, cfg: BFSConfig, frontier, visited,
 # Same level semantics as the XLA steps above, dispatched to the Pallas
 # kernels over degree-bucketed ELL tiles (repro.core.ell). Activity masking
 # replaces queue compaction: inactive rows get degree 0, so bottom-up blocks
-# of settled rows exit after zero slabs (the block-granularity early exit the
-# chunked slab while-loop provided). ELL rows preserve CSR slot order, so
+# of settled rows exit after zero slabs (where the XLA pull drops settled
+# rows from its survivor list). ELL rows preserve CSR slot order, so
 # first-hit parents are bitwise-identical to the XLA formulation.
 
 def _top_down_step_kernels(dg: DeviceGraph, cfg: BFSConfig, ell, st: BFSState):
@@ -406,7 +448,8 @@ def _advance(dg: DeviceGraph, cfg: BFSConfig, ell, st: BFSState) -> BFSState:
     else:
         next_flags, parent = jax.lax.cond(
             bu,
-            lambda s: _bottom_up_step(dg, cfg, s.frontier, s.visited, s.parent),
+            lambda s: _bottom_up_step(dg, cfg, s.frontier, s.visited,
+                                      s.parent)[:2],
             lambda s: _top_down_step(dg, cfg, s.frontier, s.visited, s.parent),
             st)
         nf = fr.count(next_flags)
@@ -540,7 +583,9 @@ class BatchState:
     decisions coincide lane-for-lane). `active` gates every cohort mask:
     a finished or pad lane is in no cohort and does no traversal work.
     `used_td`/`used_bu` record the cohort sizes of the step that produced
-    this state (the per-level direction-split observability hook).
+    this state (the per-level direction-split observability hook), and
+    `pull_rows`/`pull_slots` what its XLA pull did, summed over lanes and
+    sides: the rows it queued and the adjacency slots it gathered.
 
     Under `hub_split`, every lane carries TWO direction tracks: `bu_mode`/
     `bu_steps`/`mu` describe the TAIL side and `bu_hub`/`bu_steps_hub`/
@@ -563,6 +608,8 @@ class BatchState:
     mf: jax.Array         # int32[B]: frontier edge mass per lane
     used_td: jax.Array    # int32 scalar: tail top-down cohort of LAST step
     used_bu: jax.Array    # int32 scalar: tail bottom-up cohort of LAST step
+    pull_rows: jax.Array  # int32 scalar: rows the LAST step's pull queued
+    pull_slots: jax.Array  # int32 scalar: adjacency slots it gathered
     bu_hub: jax.Array       # bool[B]: NEXT step's hub-side direction
     bu_steps_hub: jax.Array  # int32[B]: hub-side bottom-up rounds
     mu_hub: jax.Array       # int32[B]: unvisited HUB edge mass (0 when off)
@@ -575,6 +622,7 @@ class BatchState:
         return ((self.visited, self.frontier, self.parent, self.level,
                  self.cur_level, self.active, self.bu_mode, self.bu_steps,
                  self.mu, self.nf, self.mf, self.used_td, self.used_bu,
+                 self.pull_rows, self.pull_slots,
                  self.bu_hub, self.bu_steps_hub, self.mu_hub, self.nf_hub,
                  self.mf_hub, self.used_td_hub, self.used_bu_hub), None)
 
@@ -625,10 +673,10 @@ def init_batch(dg: DeviceGraph, cfg: BFSConfig, roots, active) -> BatchState:
         bu, bu_steps = _decide_direction_batch(dg, cfg, off, zi, mu, nf, mf)
         bu_h, steps_h = bu, bu_steps
         nf_hub = mf_hub = mu_hub = zi
-    return BatchState(visited, visited, parent, level, jnp.int32(0), active,
-                      bu, bu_steps, mu, nf, mf, jnp.int32(0), jnp.int32(0),
-                      bu_h, steps_h, mu_hub, nf_hub, mf_hub,
-                      jnp.int32(0), jnp.int32(0))
+    zero = jnp.int32(0)
+    return BatchState(visited, visited, parent, level, zero, active,
+                      bu, bu_steps, mu, nf, mf, zero, zero, zero, zero,
+                      bu_h, steps_h, mu_hub, nf_hub, mf_hub, zero, zero)
 
 
 def _hub_row_mask(dg: DeviceGraph, cfg: BFSConfig):
@@ -670,10 +718,11 @@ def _decide_direction_batch(dg: DeviceGraph, cfg: BFSConfig, bu_mode,
     return bu, jnp.where(bu, bu_steps + 1, 0)
 
 
-def _lane_by_lane(step, frontier, visited, parent, mask):
-    """Run the single-root `step(f, vis, par) -> (flags, parent)` for every
-    lane in `mask`, one lane after another; lanes outside it produce no
-    flags and keep their parents, at no cost.
+def _lane_by_lane(step, frontier, visited, parent, mask, idle=()):
+    """Run the single-root `step(f, vis, par) -> (flags, parent, *extra)`
+    for every lane in `mask`, one lane after another; lanes outside it
+    produce no flags, keep their parents and report `idle` as their
+    extra outputs, at no cost.
 
     Lanes run in sequence because the per-lane loops then keep scalar trip
     counts and plain 1-D gathers and scatters. Under `vmap` a `while_loop`
@@ -685,7 +734,7 @@ def _lane_by_lane(step, frontier, visited, parent, mask):
     def lane(args):
         f, vis, par, on = args
         return jax.lax.cond(on, lambda: step(f, vis, par),
-                            lambda: (jnp.zeros_like(f), par))
+                            lambda: (jnp.zeros_like(f), par, *idle))
 
     return jax.lax.map(lane, (frontier, visited, parent, mask))
 
@@ -700,15 +749,16 @@ def _top_down_step_batch(dg: DeviceGraph, cfg: BFSConfig, frontier, visited,
 
 
 def _bottom_up_step_batch(dg: DeviceGraph, cfg: BFSConfig, frontier, visited,
-                          parent, mask, side=None, chunk=None, slab=None):
+                          parent, mask, side=None, slab=None):
     """XLA pull over the bottom-up cohort, lane by lane. `side` (bool[V],
-    lane-invariant) restricts the unvisited scan to one split side, with
-    side-tuned `chunk`/`slab` geometry."""
-    return _lane_by_lane(
+    lane-invariant) restricts the unvisited scan to one split side, with a
+    side-tuned widest `slab`. Returns `(flags, parent, counts)`, `counts`
+    the pull's queued rows and gathered slots summed over the lanes."""
+    flags, parent, counts = _lane_by_lane(
         lambda f, vis, par: _bottom_up_step(dg, cfg, f, vis, par,
-                                            row_mask=side, chunk=chunk,
-                                            slab=slab),
-        frontier, visited, parent, mask)
+                                            row_mask=side, slab=slab),
+        frontier, visited, parent, mask, idle=(jnp.zeros(2, jnp.int32),))
+    return flags, parent, jnp.sum(counts, axis=0, dtype=jnp.int32)
 
 
 def _hub_pull_batch(dg: DeviceGraph, cfg: BFSConfig, hub_rows, frontier,
@@ -719,11 +769,11 @@ def _hub_pull_batch(dg: DeviceGraph, cfg: BFSConfig, hub_rows, frontier,
     not of the search, so the row list has a static length (it is built once
     on the host and passed in with the graph, `CohortGraph.hub_rows`): the
     hub pull needs no queue compaction (the tail pays one O(V) compact; the
-    hub none) and no chunked while-loop — one slab scan over all H rows,
+    hub none) and no survivor list — one slab scan over all H rows,
     H being hundreds even at scale 22 (a row in the hub needs > floor
     edges, so H <= 2E/floor). Settled/masked rows carry degree 0 and the
     data-dependent slab cond skips them; first-hit parents are bitwise
-    those of the generic chunked scan (same slot order, same argmax rule).
+    those of the generic XLA pull (same slot order, same argmax rule).
     """
     v = dg.num_vertices
     h = hub_rows.shape[0]
@@ -842,6 +892,8 @@ def _advance_batch(cfg: BFSConfig, variant: str, graph: CohortGraph,
     bu_t_mask = st.active & bu_t
     td_h_mask = st.active & ~bu_h
     bu_h_mask = st.active & bu_h
+    no_pull = jnp.zeros(2, i32)
+    pulled = no_pull                  # the XLA pull's rows and slots
     if not cfg.hub_split:
         if variant in ("td", "mixed"):
             if use_kernels:
@@ -856,7 +908,7 @@ def _advance_batch(cfg: BFSConfig, variant: str, graph: CohortGraph,
                 flags, parent = _bottom_up_step_kernels_batch(
                     dg, cfg, ell, st.frontier, st.visited, parent, bu_t_mask)
             else:
-                flags, parent = _bottom_up_step_batch(
+                flags, parent, pulled = _bottom_up_step_batch(
                     dg, cfg, st.frontier, st.visited, parent, bu_t_mask)
             next_flags = jnp.maximum(next_flags, flags)
     else:
@@ -877,24 +929,24 @@ def _advance_batch(cfg: BFSConfig, variant: str, graph: CohortGraph,
                 dg, cfg, st.frontier, st.visited, par, lane_mask, dst_mask)
 
         def pull(par, lane_mask, hub_side):
+            """(flags, parent, counts): `counts` are the XLA tail pull's
+            rows and slots, zero for the hub side and the kernels."""
             if use_kernels:
-                return _bottom_up_step_kernels_batch(
+                return (*_bottom_up_step_kernels_batch(
                     dg, cfg, ell_hub if hub_side else ell_tail, st.frontier,
-                    st.visited, par, lane_mask, hub_kernel=hub_side)
+                    st.visited, par, lane_mask, hub_kernel=hub_side),
+                    no_pull)
             if hub_side:
                 if hub_list.shape[0] == 0:
-                    return jnp.zeros_like(st.frontier), par
-                return _hub_pull_batch(dg, cfg, hub_list, st.frontier,
-                                       st.visited, par, lane_mask)
-            # Tail-tuned chunking is the split's other XLA win: tail rows
-            # are degree-bounded by the snapped hub floor, so one wide row
-            # can no longer convoy a whole chunk through hundreds of slab
-            # iterations — the tail safely takes chunks 4x wider (fewer
-            # while-loop trips over the big unvisited queue). Chunk/slab
-            # regrouping never changes first-hit parents.
+                    return jnp.zeros_like(st.frontier), par, no_pull
+                return (*_hub_pull_batch(dg, cfg, hub_list, st.frontier,
+                                         st.visited, par, lane_mask),
+                        no_pull)
+            # The tail pull is the generic one restricted to tail rows;
+            # grouping rows by side never changes first-hit parents.
             return _bottom_up_step_batch(
                 dg, cfg, st.frontier, st.visited, par, lane_mask,
-                side=tail_pull, chunk=4 * cfg.bu_chunk, slab=cfg.bu_slab)
+                side=tail_pull, slab=cfg.bu_slab)
 
         if variant == "td":
             # Both sides push: one unmasked pass covers hub + tail targets.
@@ -906,10 +958,11 @@ def _advance_batch(cfg: BFSConfig, variant: str, graph: CohortGraph,
                 next_flags = jnp.maximum(next_flags, flags)
                 flags, parent = push(parent, td_h_mask, hub_v)
                 next_flags = jnp.maximum(next_flags, flags)
-            flags, parent = pull(parent, bu_t_mask, False)
+            flags, parent, pulled = pull(parent, bu_t_mask, False)
             next_flags = jnp.maximum(next_flags, flags)
-            flags, parent = pull(parent, bu_h_mask, True)
+            flags, parent, hub_pulled = pull(parent, bu_h_mask, True)
             next_flags = jnp.maximum(next_flags, flags)
+            pulled = pulled + hub_pulled
     if use_kernels:
         _, nf, mf = K.frontier_fused_batch(next_flags, dg.deg_ext[:-1])
     else:
@@ -942,7 +995,7 @@ def _advance_batch(cfg: BFSConfig, variant: str, graph: CohortGraph,
     return BatchState(visited, next_flags, parent, level, cur, active,
                       bu2, steps2, mu, nf, mf,
                       jnp.sum(td_t_mask.astype(i32)),
-                      jnp.sum(bu_t_mask.astype(i32)),
+                      jnp.sum(bu_t_mask.astype(i32)), pulled[0], pulled[1],
                       bu_h2, steps_h2, mu_hub, nf_hub, mf_hub,
                       jnp.sum(td_h_mask.astype(i32)) if cfg.hub_split
                       else jnp.int32(0),
@@ -997,7 +1050,8 @@ def batch_scalars(st: BatchState) -> dict:
     can be in both when its sides disagree; with the split off `bu_hub`
     mirrors `bu_mode` and the counts collapse to the unsplit schema), and
     the `*_hub` keys expose the hub side's cohort sizes and frontier mass
-    for the per-level occupancy rows.
+    for the per-level occupancy rows. `pull_rows`/`pull_slots` are the
+    last step's pull counters.
     """
     act = st.active
     i32 = jnp.int32
@@ -1011,6 +1065,8 @@ def batch_scalars(st: BatchState) -> dict:
         active_n=jnp.sum(act.astype(i32)),
         used_td=st.used_td,
         used_bu=st.used_bu,
+        pull_rows=st.pull_rows,
+        pull_slots=st.pull_slots,
         used_td_hub=st.used_td_hub,
         used_bu_hub=st.used_bu_hub,
         nf_hub=jnp.sum(jnp.where(act, st.nf_hub, 0), dtype=i32),

@@ -61,6 +61,9 @@ Extended (batched-cohort) protocol, opted into per backend:
   the level's stats row — `pre` is the sync entering the step (per-lane
   frontier stats, the directions the step used), `post` the one after it
   (realized cohort sizes). It may override "direction" (e.g. "mixed").
+* `step_attrs` (optional) names row fields the driver also copies onto
+  the level's `repro.level.step` span (`CohortBatchBackend`: the pull
+  counters `pull_rows`/`pull_slots`).
 """
 from __future__ import annotations
 
@@ -252,6 +255,7 @@ class CohortBatchBackend:
 
     has_exchange = False
     needs_sync = True
+    step_attrs = ("pull_rows", "pull_slots")
 
     def __init__(self, init_fn: Callable, step_fns: dict,
                  scalars_fn: Callable, num_vertices: int, bucket: int):
@@ -334,6 +338,8 @@ class CohortBatchBackend:
             lane_hub_frontier=[int(x) for x in pre.get("nf_hub_lanes",
                                                        [0] * self.bucket)],
             lane_active=[bool(x) for x in pre["active_lanes"]],
+            pull_rows=int(post["pull_rows"]),
+            pull_slots=int(post["pull_slots"]),
         )
 
 
@@ -379,6 +385,7 @@ class LevelDriver:
         b = self.backend
         needs_sync = getattr(b, "needs_sync", False)
         row_extra = getattr(b, "row_extra", None)
+        step_attrs = getattr(b, "step_attrs", ())
         with span("repro.level.init") as init:
             state = b.init(root)
             # repro-ok: TH001 timing fence: init_s must not absorb async dispatch of the first level
@@ -428,6 +435,7 @@ class LevelDriver:
             if row_extra is not None:
                 row.update(row_extra(pre, post))
             step.attrs.setdefault("variant", row["direction"])
+            step.attrs.update((k, row[k]) for k in step_attrs)
             stats.append(row)
             if on_level:
                 on_level(row)
