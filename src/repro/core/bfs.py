@@ -3,12 +3,12 @@
 Faithful to Beamer et al. / the paper's Algorithm 1, formulated with static
 shapes so the whole search (or one level) is a compiled XLA program:
 
-* **Top-down (push)**: the frontier is compacted into a queue; a
-  `lax.while_loop` walks its *edge slots* in fixed-size chunks (work
-  proportional to frontier edge mass, the direction-optimization invariant).
-  Ownership of an edge slot is recovered with a vectorized `searchsorted`
-  over the queue's degree prefix sum — the TPU-native replacement for the
-  GPU's per-thread edge binning ("virtual warp" has no TPU analogue; see
+* **Top-down (push)**: a `lax.while_loop` walks the frontier's *edge
+  slots* in fixed-size chunks (trips proportional to frontier edge mass,
+  the direction-optimization invariant). The slot's source vertex is
+  recovered with a vectorized `searchsorted` over a dense, vertex-order
+  prefix sum of the frontier's degrees — the TPU-native replacement for
+  the GPU's per-thread edge binning ("virtual warp" has no TPU analogue; see
   API.md §Kernel-backed traversal).
 * **Bottom-up (pull)**: unvisited vertices are queued, and the queue is
   walked slab by slab over a list of survivors: each slab gathers a few
@@ -210,10 +210,17 @@ def init_state(dg: DeviceGraph, root) -> BFSState:
 
 def _top_down_step(dg: DeviceGraph, cfg: BFSConfig, frontier, visited, parent,
                    dst_mask=None):
-    """One push level: work ~ frontier edge mass, chunked.
+    """One push level: the frontier's edge slots, `td_chunk` at a time.
 
     Takes the flat (frontier, visited, parent) triple rather than a
     `BFSState` so the batched cohort path can run it lane by lane.
+
+    Slots are numbered by a dense, vertex-order prefix sum of the
+    frontier's degrees, so a slot's source is the vertex whose range holds
+    it (`searchsorted` over that sum): vertices off the frontier, and those
+    of degree 0, hold no slot. The level starts with elementwise passes
+    and one cumsum over V and no gather or scatter; the chunk loop's trips
+    follow the frontier's edge mass.
 
     `dst_mask` (bool[V] or None) restricts which DESTINATIONS this pass may
     discover — the heterogeneous split's side filter. The scatter-min parent
@@ -222,22 +229,20 @@ def _top_down_step(dg: DeviceGraph, cfg: BFSConfig, frontier, visited, parent,
     """
     v = dg.num_vertices
     c = cfg.td_chunk
-    queue, _n = fr.compact(frontier)             # fill entries == v
-    degq = dg.deg_ext[queue]                     # 0 for fill
-    cum = jnp.cumsum(degq, dtype=jnp.int32)
+    e_last = max(dg.num_directed_edges - 1, 0)
+    degf = jnp.where(frontier > 0, dg.deg_ext[:v], 0)
+    cum = jnp.cumsum(degf, dtype=jnp.int32)
     total = cum[-1] if v else jnp.int32(0)
+    # A frontier vertex's edge index less its first slot number.
+    off = dg.indptr[:v] - (cum - degf)
 
     def body(carry):
         base, next_flags, pcand = carry
         slots = base + jnp.arange(c, dtype=jnp.int32)
         valid = slots < total
-        owner = jnp.searchsorted(cum, slots, side="right").astype(jnp.int32)
-        owner = jnp.minimum(owner, v - 1)
-        src = queue[owner]
-        src = jnp.minimum(src, v - 1)            # fill guard (valid==False)
-        start = cum[owner] - degq[owner]
-        eidx = dg.indptr[src] + (slots - start)
-        eidx = jnp.clip(eidx, 0, max(dg.num_directed_edges - 1, 0))
+        src = jnp.searchsorted(cum, slots, side="right").astype(jnp.int32)
+        src = jnp.minimum(src, v - 1)            # past total (valid==False)
+        eidx = jnp.clip(off[src] + slots, 0, e_last)
         dst = jnp.where(valid, dg.indices[eidx], 0)
         fresh = valid & (visited[dst] == 0)
         if dst_mask is not None:
